@@ -6,6 +6,7 @@ plus richer per-table output to stderr-safe stdout sections.
 
 from __future__ import annotations
 
+import dataclasses
 import itertools
 import math
 from typing import Callable
@@ -188,6 +189,22 @@ def paper_settings(quick: bool = True) -> EvaluationSettings:
     return EvaluationSettings(max_invocations=10, max_iterations=200,
                               max_time_s=10.0,
                               direction=Direction.MAXIMIZE)
+
+
+def session_settings(benchmark: str, quick: bool = True,
+                     ) -> EvaluationSettings:
+    """Settings of a CI-pruned tuning session (``scripts/tune.py``,
+    ``chip_smoke.py``): the paper's budgets with CI convergence and both
+    prunes on. TRIAD keeps convergence but never prunes: each working
+    set probes its own memory subsystem, so the sizes are measurements,
+    not competitors, and pruning a slow stream against a faster one
+    would cache a truncated bandwidth and drop that subsystem from the
+    roofline report."""
+    prune = benchmark != "triad"
+    return dataclasses.replace(paper_settings(quick),
+                               use_ci_convergence=True,
+                               use_inner_prune=prune,
+                               use_outer_prune=prune)
 
 
 def dgemm_benchmark(cfg: dict) -> Callable:
@@ -394,7 +411,7 @@ def model_step_space(quick: bool = True) -> SearchSpace:
                 flash_block_k=(64, 128, 256, 512), remat=(0, 1))
 
 
-def _model_step(workload: str, arch, cfg: dict, *,
+def model_step_workload(workload: str, arch, cfg: dict, *,
                 batch_size: int, seq_len: int):
     """Build one workload under a tuner config (shared by the timed
     factory, the audit spec, and the precompile hook)."""
@@ -410,20 +427,23 @@ def _model_step(workload: str, arch, cfg: dict, *,
                           batch_size=batch_size, seq_len=seq_len)
 
 
-def model_step_family(workload: str, arch: str | None = None, *,
+def model_step_family(workload: str, arch=None, *,
                       batch_size: int = 2, seq_len: int = 64) -> Callable:
     """Benchmark family for one whole-model step (train/prefill/decode).
 
     ``workload`` names a :mod:`repro.models.workloads` builder; ``arch``
-    picks a smoke-scale architecture (default: the tiny dense toy). The
+    picks a smoke-scale architecture by name, or is a ``ModelConfig``
+    built as given (default: the tiny dense toy). The
     returned ``bench(cfg)`` exposes ``audit_spec`` and ``precompile``
     like the microbenchmarks, so model steps ride the same lint, AOT
     cache, and pipelined-compile machinery.
     """
     from repro.models.workloads import workload_static_cost
 
+    arch_name = getattr(arch, "name", arch) or "tiny-dense"
+
     def bench(cfg: dict) -> Callable:
-        w = _model_step(workload, arch, cfg,
+        w = model_step_workload(workload, arch, cfg,
                         batch_size=batch_size, seq_len=seq_len)
         flops = workload_static_cost(w).flops
         state: dict = {"compiled": None}
@@ -434,7 +454,7 @@ def model_step_family(workload: str, arch: str | None = None, *,
             f = state["compiled"]
             jax.block_until_ready(f(*w.args))   # pre-heat
             trace_instant("workload", kernel=workload,
-                          arch=arch or "tiny-dense", flops=flops,
+                          arch=arch_name, flops=flops,
                           **{k: cfg[k] for k in sorted(cfg)})
 
             def run():
@@ -445,16 +465,16 @@ def model_step_family(workload: str, arch: str | None = None, *,
         return factory
 
     def model_audit_spec(cfg: dict) -> WorkloadSpec:
-        w = _model_step(workload, arch, cfg,
+        w = model_step_workload(workload, arch, cfg,
                         batch_size=batch_size, seq_len=seq_len)
         return WorkloadSpec(
             fn=w.fn, args=w.args,
             work=workload_static_cost(w).flops, unit="flops",
-            name=f"{workload}[{arch or 'tiny-dense'}"
+            name=f"{workload}[{arch_name}"
                  f" b{batch_size} s{seq_len}]")
 
     def model_precompile(cfg: dict) -> None:
-        w = _model_step(workload, arch, cfg,
+        w = model_step_workload(workload, arch, cfg,
                         batch_size=batch_size, seq_len=seq_len)
         w.compiled()
 

@@ -15,6 +15,8 @@ import inspect
 import sys
 import time
 
+from repro.core.exec_cache import enable_compile_cache
+
 from . import (bench_distributed_tuner, bench_iteration_counts,
                bench_kernel_autotune, bench_matmul_peak, bench_optimizations,
                bench_roofline_model, bench_size_sweep, bench_triad)
@@ -51,6 +53,7 @@ def main() -> None:
                          "dashboard (rooflines + run-ledger trends and "
                          "regression verdicts) from the --resume cache dir")
     args = ap.parse_args()
+    enable_compile_cache()
     quick = not args.full
 
     print("name,us_per_call,derived")

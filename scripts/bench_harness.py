@@ -382,6 +382,9 @@ def main() -> int:
     if args.check:
         return check(path)       # deterministic: no jax, no obs imports
 
+    from repro.core.exec_cache import enable_compile_cache
+    enable_compile_cache()
+
     # the measuring run is itself traced: every legacy/fast session's
     # trial/invocation/phase spans land in one JSONL + Perfetto artifact
     # next to the JSON (uploaded by CI) — the harness eating its own

@@ -29,7 +29,9 @@ device tracks (static HLO-only attribution otherwise), classifies every
 op against the empirical roofs recovered from the given caches, and adds
 a per-op attribution section to the markdown and HTML dashboards (see
 ``docs/attribution.md``). Cache paths become optional in this mode; with
-no usable cache the theoretical TPU-v5e roofs stand in (clearly marked).
+no usable cache the published peaks of the device the workload runs on
+stand in (clearly marked), and a device kind without published peaks is
+an error.
 """
 
 from __future__ import annotations
@@ -44,6 +46,7 @@ for p in (str(_REPO), str(_REPO / "src")):
         sys.path.insert(0, p)
 
 from repro.core import build_reports, load_trials  # noqa: E402
+from repro.core.exec_cache import enable_compile_cache  # noqa: E402
 from repro.core.report import (DGEMM_BENCHMARK, TRIAD_BENCHMARK,  # noqa: E402
                                render_csv, render_markdown)
 
@@ -92,6 +95,7 @@ def main() -> int:
                     help="write the --attribute report as JSON (CI "
                          "artifact)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if not args.paths and not args.attribute:
         ap.error("at least one cache path is required (or --attribute)")
@@ -125,7 +129,9 @@ def main() -> int:
 
     attribution = None
     if args.attribute:
-        from repro.core.roofline import TPU_V5E  # noqa: E402
+        import jax  # noqa: E402
+
+        from repro.core.roofline import published_peaks  # noqa: E402
         from repro.models.workloads import build_workload  # noqa: E402
         from repro.obs.attribution import Roofs, attribute  # noqa: E402
         from repro.obs.attribution import roofs_from_trials  # noqa: E402
@@ -133,13 +139,20 @@ def main() -> int:
         roofs = roofs_from_trials(args.paths) if args.paths else None
         if roofs is None:
             # no empirical roofs in the caches: classify against the
-            # shipped theoretical machine description, clearly marked
-            roofs = Roofs(peak_flops=TPU_V5E.peak_flops,
-                          bandwidths=dict(TPU_V5E.mem_bandwidths),
-                          fingerprint=f"{TPU_V5E.name} (theoretical)")
+            # published peaks of the device the workload runs on, clearly
+            # marked; a device the table lacks is an error, not a default
+            kind = jax.devices()[0].device_kind
+            try:
+                spec = published_peaks(kind)
+            except KeyError as e:
+                print(f"error: no empirical roofs in the given caches and "
+                      f"{e.args[0]}", file=sys.stderr)
+                return 2
+            roofs = Roofs(peak_flops=spec.peak_flops,
+                          bandwidths=dict(spec.mem_bandwidths),
+                          fingerprint=f"{spec.name} (published)")
             print(f"note: no empirical roofs recovered; classifying "
-                  f"against {TPU_V5E.name} theoretical peaks",
-                  file=sys.stderr)
+                  f"against {spec.name} published peaks", file=sys.stderr)
         try:
             workload = build_workload(args.attribute, args.arch)
         except ValueError as e:

@@ -37,6 +37,7 @@ import dataclasses  # noqa: E402
 from repro.core import (SearchSpace, TrialCache, Tuner,  # noqa: E402
                         hardware_fingerprint, param)
 from repro.core.cache import config_key  # noqa: E402
+from repro.core.exec_cache import enable_compile_cache  # noqa: E402
 from repro.sweep import SweepCampaign, parse_shape_key, shape_key  # noqa: E402
 
 from tune import parse_backend  # noqa: E402  (shared CLI backend specs)
@@ -117,6 +118,7 @@ def main() -> int:
                          "(default path <cache-dir>/<session>.trace.jsonl; "
                          "see docs/observability.md)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from benchmarks.common import (chunked_dgemm_family, gemm_shape_space,
                                    paper_settings, sweep_chunk_space,

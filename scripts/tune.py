@@ -43,13 +43,12 @@ for p in (str(_REPO), str(_REPO / "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-import dataclasses  # noqa: E402
-
 from repro.core import (NeighborhoodStrategy, ProcessPoolBackend,  # noqa: E402
                         RandomSearchStrategy, SerialBackend,
                         SimulatedShardedBackend, SuccessiveHalvingStrategy,
                         ThreadPoolBackend, TrialCache, Tuner, TuningSession,
                         hardware_fingerprint)
+from repro.core.exec_cache import enable_compile_cache  # noqa: E402
 
 STRATEGIES = ("exhaustive", "halving", "random", "neighborhood",
               "surrogate", "bandit")
@@ -170,6 +169,7 @@ def main() -> int:
                          "older superseded runs; runs after tuning, or "
                          "standalone when --session is omitted")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.session is None:
         if args.compact_history is None:
@@ -178,14 +178,11 @@ def main() -> int:
         return compact_history(args)
 
     from benchmarks.common import (dgemm_benchmark, dgemm_space,
-                                   paper_settings, synthetic_benchmark,
+                                   session_settings, synthetic_benchmark,
                                    triad_benchmark)
 
     quick = not args.full
-    settings = dataclasses.replace(paper_settings(quick),
-                                   use_ci_convergence=True,
-                                   use_inner_prune=True,
-                                   use_outer_prune=True)
+    settings = session_settings(args.benchmark, quick)
     if args.benchmark == "dgemm":
         space, benchmark = dgemm_space(quick), dgemm_benchmark
     elif args.benchmark == "synthetic":
@@ -198,12 +195,6 @@ def main() -> int:
             tuple(2 ** e for e in range(14, 28, 2))
         space = grid(n_bytes=sizes)
         benchmark = triad_benchmark
-        # Each TRIAD size probes a different memory subsystem: the sizes
-        # are measurements, not competitors. Pruning a slow DRAM stream
-        # against the cache-resident incumbent would cache a truncated
-        # bandwidth estimate and drop that subsystem from --report.
-        settings = dataclasses.replace(settings, use_inner_prune=False,
-                                       use_outer_prune=False)
 
     cache_path = pathlib.Path(args.cache_dir) / f"{args.session}.jsonl"
     if args.fresh and cache_path.exists():

@@ -25,6 +25,7 @@ term against this traced cost (falling back to it when the backend's
 from __future__ import annotations
 
 import dataclasses
+import math
 import re
 from collections import defaultdict
 
@@ -41,10 +42,15 @@ _DTYPE_BYTES = {
 _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
                 "collective-permute")
 
+# one result shape: an array with an optional layout, whose TPU form
+# nests parens (f32[8,128]{1,0:T(8,128)S(1)}), or a tuple of them
+_SHAPE_TEXT = (r"(?:\((?:[^()]|\([^()]*\))*\)"
+               r"|[a-z0-9]+\[[0-9,]*\](?:\{[^}]*\})?)")
+
 # e.g.  %ag = bf16[16,1024]{1,0} all-gather(%x), ...
 #       ROOT %tuple = (f32[4]{0}, f32[4]{0}) all-reduce(...)
 _OP_RE = re.compile(
-    r"=\s*(?P<shape>\([^)]*\)|[a-z0-9]+\[[0-9,]*\](?:\{[^}]*\})?)\s*"
+    r"=\s*(?P<shape>" + _SHAPE_TEXT + r")\s*"
     r"(?P<op>all-reduce|all-gather|reduce-scatter|all-to-all|"
     r"collective-permute)(?:-start|-done)?\(",
 )
@@ -139,11 +145,10 @@ _REDUCE_OPS = frozenset({"reduce", "reduce-window"})
 # generic "name = shape op(" — the op token is the word before the operand
 # list; versioned names (%add.0) carry the version after the paren match
 _COST_OP_RE = re.compile(
-    r"=\s*(?P<shape>\([^)]*\)|[a-z0-9]+\[[0-9,]*\](?:\{[^}]*\})?)\s*"
-    r"(?P<op>[a-z][a-z0-9-]*)\(",
-)
+    r"=\s*(?P<shape>" + _SHAPE_TEXT + r")\s*(?P<op>[a-z][a-z0-9-]*)\(")
 
 _CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([0-9,]*)\}")
+_DIM_LABELS_RE = re.compile(r"dim_labels=[^_\s]+_(?P<rhs>[^-\s]+)->")
 
 
 def _shape_numel(shape_str: str) -> int:
@@ -176,6 +181,41 @@ def _operand_text(line: str, start: int) -> str:
     return line[start + 1:]
 
 
+def _split_operands(text: str) -> list[str]:
+    """Top-level comma split of an operand list (shapes carry commas
+    inside ``[...]``, layouts inside ``{...}``, tuples inside ``(...)``)."""
+    parts: list[str] = []
+    depth = 0
+    start = 0
+    for i, ch in enumerate(text):
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+        elif ch == "," and depth == 0:
+            parts.append(text[start:i])
+            start = i + 1
+    parts.append(text[start:])
+    return [p.strip() for p in parts if p.strip()]
+
+
+def _operand_shapes(operands: str, symbols: dict[str, str]) -> str:
+    """The operand list rewritten as its shapes, space-separated.
+
+    Older XLA prints each operand with its shape (``f32[64,32]{1,0}
+    %a``); newer XLA prints bare names (``%a.1``), so a bare name is
+    resolved through ``symbols``, the enclosing computation's
+    ``name -> result shape`` table. Unknown names resolve to nothing
+    (zero bytes) rather than guessing."""
+    shapes = []
+    for part in _split_operands(operands):
+        if _SHAPE_RE.match(part) or part.startswith("("):
+            shapes.append(part.rsplit("%", 1)[0] if "%" in part else part)
+        else:
+            shapes.append(symbols.get(part.lstrip("%"), ""))
+    return " ".join(s for s in shapes if s)
+
+
 @dataclasses.dataclass(frozen=True)
 class HloCost:
     """Text-derived compute/memory cost estimate of one HLO module.
@@ -203,8 +243,10 @@ class HloCost:
 def _op_cost(op: str, shape: str, operands: str,
              line: str) -> "tuple[float, float] | None":
     """(flops, bytes) of one costed instruction, ``None`` when the op kind
-    is not modeled. Shared by the module-aggregate and per-op parsers so
-    the two views can never disagree on a single instruction."""
+    is not modeled. ``operands`` is the resolved operand shape text
+    (:func:`_operand_shapes`). Shared by the module-aggregate and per-op
+    parsers so the two views can never disagree on a single
+    instruction."""
     moved = _shape_bytes(shape) + _shape_bytes(operands)
     if op == "dot":
         lhs = _SHAPE_RE.search(operands)
@@ -218,6 +260,22 @@ def _op_cost(op: str, shape: str, operands: str,
                 if 0 <= i < len(dims):
                     contract *= dims[i]
         return 2.0 * _shape_numel(shape) * contract, float(moved)
+    if op == "convolution":
+        # the TPU compiler emits matmuls as convolutions
+        # (dim_labels=bf_io->bf): each output element accumulates one
+        # product per kernel element outside the kernel's output-feature
+        # dim 'o'
+        kernel = list(_SHAPE_RE.finditer(operands))[1:2]
+        labels = _DIM_LABELS_RE.search(line)
+        if not kernel or labels is None:
+            return None
+        dims = [int(d) for d in kernel[0].group("dims").split(",")
+                if d]
+        o_dim = labels.group("rhs").find("o")
+        if len(dims) != len(labels.group("rhs")) or o_dim < 0:
+            return None
+        per_output = math.prod(dims) // dims[o_dim]
+        return 2.0 * _shape_numel(shape) * per_output, float(moved)
     if op in _ELEMENTWISE_OPS:
         return float(_shape_numel(shape)), float(moved)
     if op in _REDUCE_OPS:
@@ -240,15 +298,9 @@ def parse_hlo_cost(hlo_text: str) -> HloCost:
     flops_by_op: dict[str, float] = defaultdict(float)
     bytes_by_op: dict[str, float] = defaultdict(float)
     unhandled: dict[str, int] = defaultdict(int)
-    for line in hlo_text.splitlines():
-        m = _COST_OP_RE.search(line)
-        if m is None:
-            continue
-        op = m.group("op")
-        shape = m.group("shape")
+    for _, _, _, op, shape, operands, line in _instructions(hlo_text):
         if op in _SKIP_OPS or op.endswith("-start") or op.endswith("-done"):
             continue
-        operands = _operand_text(line, m.end() - 1)
         cost = _op_cost(op, shape, operands, line)
         if cost is None:
             unhandled[op] += 1
@@ -285,6 +337,35 @@ _COMP_RE = re.compile(
 _INSTR_NAME_RE = re.compile(r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.$-]+)\s*=")
 
 _CALLS_RE = re.compile(r"(?:calls|to_apply)=%?(?P<callee>[\w.$-]+)")
+
+
+def _instructions(hlo_text: str):
+    """Yield ``(computation, is_entry, name, op, shape, operand_shapes,
+    line)`` for every instruction line, with operand shapes resolved
+    through the computation's symbol table (definitions precede uses in
+    HLO text). Lines before any computation header (handwritten
+    fixtures) belong to the computation named ``""``."""
+    current = ""
+    is_entry = False
+    symbols: dict[str, str] = {}
+    for line in hlo_text.splitlines():
+        if not line.startswith((" ", "\t")):
+            cm = _COMP_RE.match(line)
+            if cm and line.rstrip().endswith("{") \
+                    and ("->" in line or cm.group("entry")):
+                current = cm.group("name")
+                is_entry = bool(cm.group("entry"))
+                symbols = {}
+                continue
+        m = _COST_OP_RE.search(line)
+        if m is None:
+            continue
+        nm = _INSTR_NAME_RE.match(line)
+        name = nm.group("name") if nm else m.group("op")
+        shape = m.group("shape")
+        operands = _operand_shapes(_operand_text(line, m.end() - 1), symbols)
+        symbols[name] = shape
+        yield current, is_entry, name, m.group("op"), shape, operands, line
 
 
 @dataclasses.dataclass(frozen=True)
@@ -346,25 +427,11 @@ def parse_hlo_ops(hlo_text: str) -> ModuleOps:
     # pass 1: bucket instruction lines per computation
     comps: dict[str, list[tuple[str, str, str, str, str]]] = {}
     entry: "str | None" = None
-    current = ""
-    for line in hlo_text.splitlines():
-        if not line.startswith((" ", "\t")):
-            cm = _COMP_RE.match(line)
-            if cm and line.rstrip().endswith("{") \
-                    and ("->" in line or cm.group("entry")):
-                current = cm.group("name")
-                comps.setdefault(current, [])
-                if cm.group("entry"):
-                    entry = current
-                continue
-        m = _COST_OP_RE.search(line)
-        if m is None:
-            continue
-        nm = _INSTR_NAME_RE.match(line)
-        name = nm.group("name") if nm else m.group("op")
-        operands = _operand_text(line, m.end() - 1)
-        comps.setdefault(current, []).append(
-            (name, m.group("op"), m.group("shape"), operands, line))
+    for comp, is_entry, name, op, shape, operands, line in \
+            _instructions(hlo_text):
+        if is_entry:
+            entry = comp
+        comps.setdefault(comp, []).append((name, op, shape, operands, line))
     if entry is None:
         # handwritten fixtures without an ENTRY header: the implicit
         # top-level bucket (or the only computation present)

@@ -101,7 +101,7 @@ class EvaluationSettings:
     # Opt-in on-device timing (repro.obs.device_timing): when a trace
     # recorder is installed, trials that beat the incumbent get one extra
     # profiled invocation whose device-side kernel time and host-vs-device
-    # skew land in the trace. Off-GPU/TPU it degrades to an
+    # skew land in the trace. On the CPU backend it degrades to an
     # "unavailable" instant. Never touches the measured samples.
     device_timing: bool = False
 
@@ -276,11 +276,8 @@ class Evaluator:
         inc = _resolve_incumbent(incumbent)
         if inc is not None and not direction.better(score, inc):
             return
-        try:
-            from repro.obs.device_timing import profile_sample
-            timing = profile_sample(sample_fn)
-        except Exception:
-            timing = None
+        from repro.obs.device_timing import profile_sample
+        timing = profile_sample(sample_fn)   # raises on an accelerator
         if timing is None:
             trace_instant("device_timing_unavailable")
         else:

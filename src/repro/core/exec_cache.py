@@ -29,15 +29,21 @@ importable without initializing a backend.
 
 from __future__ import annotations
 
+import os
 import threading
 import time
 from collections import OrderedDict
+from pathlib import Path
 from typing import Any, Callable, Mapping, Optional, Sequence
 
 from .profiling import phase, trace_instant
 
-__all__ = ["CompilePipeline", "ExecCacheStats", "ExecutableCache",
-           "default_cache"]
+__all__ = ["COMPILE_CACHE_DIR", "CompilePipeline", "ExecCacheStats",
+           "ExecutableCache", "default_cache", "enable_compile_cache"]
+
+#: where JAX's persistent compilation cache lives when
+#: ``JAX_COMPILATION_CACHE_DIR`` is unset: ``<repo>/.jax_cache``
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def _arg_key(a: Any) -> tuple:
@@ -263,6 +269,26 @@ def default_cache() -> ExecutableCache:
         if _DEFAULT is None:
             _DEFAULT = ExecutableCache()
         return _DEFAULT
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    Called once by each entry point (scripts, benchmark runner, chip
+    smoke test) before anything compiles, never by library code or
+    tests. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it
+    itself and this sets nothing. Otherwise the cache goes to the fixed
+    :data:`COMPILE_CACHE_DIR`: the directory is part of what lets a
+    later process find an entry, so it is never made from a temporary
+    name, a pid or the time.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)
 
 
 class CompilePipeline:

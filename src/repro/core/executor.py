@@ -474,6 +474,10 @@ class ProcessPoolBackend(ExecutionBackend):
     startup per pool — amortized over a search, and the only start method
     that is safe after jax initialization. Pass ``start_method="fork"``
     only for jax-free objectives where startup dominates.
+
+    It runs only where jax's default backend is the CPU: an accelerator
+    belongs to one process at a time, so on a TPU host the pool refuses
+    to start, before any worker is spawned.
     """
 
     name = "process"
@@ -491,6 +495,18 @@ class ProcessPoolBackend(ExecutionBackend):
 
     def _make_pool(self) -> ProcessPoolExecutor:
         import multiprocessing
+
+        import jax
+
+        backend = jax.default_backend()
+        if backend != "cpu":
+            # an accelerator belongs to one process at a time: this one
+            # holds it now, so a worker that imports jax would fail or hang
+            raise RuntimeError(
+                f"ProcessPoolBackend refuses to start workers: this process "
+                f"holds the {backend!r} backend, and an accelerator serves "
+                f"one process at a time. Use the serial or thread backend "
+                f"on an accelerator host.")
         try:
             mp_ctx = multiprocessing.get_context(self.start_method)
         except ValueError:                       # platform without it

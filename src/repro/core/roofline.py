@@ -5,8 +5,9 @@
 
 The paper's tool emits this model from *measured* peaks (autotuned DGEMM for
 F_p, autotuned TRIAD for each memory subsystem's B_a) with no vendor specs.
-We keep that shape, and additionally ship the TPU-v5e theoretical machine
-description used by the dry-run roofline analysis (EXPERIMENTS.md §Roofline).
+We keep that shape, and additionally ship the published peaks of the
+chips the tuner targets (:data:`PUBLISHED_PEAKS`), which the dry-run
+roofline analysis and the measured-roof sanity checks compare against.
 """
 
 from __future__ import annotations
@@ -25,6 +26,7 @@ class MachineSpec:
     mem_bandwidths: Mapping[str, float]    # subsystem name -> bytes/s
     link_bandwidth: float = 0.0            # bytes/s per ICI link (TPU)
     chips: int = 1
+    peak_int8_ops: float = 0.0             # int8 OP/s (0: not published)
 
     @property
     def total_flops(self) -> float:
@@ -34,13 +36,33 @@ class MachineSpec:
         return self.mem_bandwidths[subsystem] * self.chips
 
 
-# TPU v5e constants given by the assignment (per chip).
-TPU_V5E = MachineSpec(
-    name="tpu-v5e",
-    peak_flops=197e12,                     # bf16
-    mem_bandwidths={"hbm": 819e9},
-    link_bandwidth=50e9,
-)
+#: Published per-chip peaks, keyed by ``jax.Device.device_kind``.
+#: Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16,
+#: 393 TOP/s int8, 16 GB of HBM at 819 GB/s, 1,600 Gbit/s of
+#: chip-to-chip interconnect (4 links of 50 GB/s). A kind missing here
+#: is an error (:func:`published_peaks`), never a default.
+PUBLISHED_PEAKS: dict[str, MachineSpec] = {
+    "TPU v5 lite": MachineSpec(
+        name="tpu-v5e",
+        peak_flops=197e12,                 # bf16
+        mem_bandwidths={"hbm": 819e9},
+        link_bandwidth=50e9,
+        peak_int8_ops=393e12,
+    ),
+}
+
+TPU_V5E = PUBLISHED_PEAKS["TPU v5 lite"]
+
+
+def published_peaks(device_kind: str) -> MachineSpec:
+    """The published peaks of one device kind; ``KeyError`` naming the
+    kind when the table lacks it."""
+    try:
+        return PUBLISHED_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r} (known: {sorted(PUBLISHED_PEAKS)})"
+                       ) from None
 
 
 def attainable(intensity: float, peak_flops: float, bandwidth: float) -> float:

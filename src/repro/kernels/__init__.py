@@ -2,7 +2,8 @@
 
 Each kernel subpackage ships:
   * ``<name>.py`` — the ``pl.pallas_call`` kernel with explicit BlockSpec
-    VMEM tiling (TPU is the target; ``interpret=True`` validates on CPU);
+    VMEM tiling (TPU is the target; Pallas interpret mode validates on
+    the CPU, and :func:`interpret_mode` is the one place that decides it);
   * ``ops.py``    — the jit'd public wrapper (padding, dtype policy, block
     autotuning hooks);
   * ``ref.py``    — a pure-jnp oracle used by tests and as the XLA fallback
@@ -23,3 +24,4 @@ Kernels:
 """
 
 from . import flash_attention, matmul, ssd, triad  # noqa: F401
+from .platform import interpret_mode  # noqa: F401

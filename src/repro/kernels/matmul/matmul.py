@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...compat import tpu_compiler_params
-
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k_steps: int):
     """One (bm, bn) output tile; accumulates over the sequential k axis."""
@@ -45,7 +43,8 @@ def matmul_pallas(a: jax.Array, b: jax.Array, *, bm: int = 512, bn: int = 512,
     """C = A @ B with explicit (bm, bn, bk) VMEM tiling.
 
     Requires shapes divisible by the tile sizes; ``ops.matmul`` handles
-    padding. ``interpret=True`` runs the kernel body in Python on CPU.
+    padding. Interpret mode (``interpret``) runs the kernel body in
+    Python on the CPU.
     """
     m, k = a.shape
     k2, n = b.shape
@@ -66,7 +65,7 @@ def matmul_pallas(a: jax.Array, b: jax.Array, *, bm: int = 512, bn: int = 512,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

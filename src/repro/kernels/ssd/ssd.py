@@ -30,11 +30,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ...compat import tpu_compiler_params
 
-
-def _ssd_kernel(xdt_ref, bm_ref, cm_ref, cum_ref, y_ref, h_ref, *,
-                q_len: int):
+def _ssd_kernel(xdt_ref, bm_ref, cm_ref, cum_col_ref, cum_row_ref, y_ref,
+                h_ref, *, q_len: int):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -44,10 +42,11 @@ def _ssd_kernel(xdt_ref, bm_ref, cm_ref, cum_ref, y_ref, h_ref, *,
     xdt = xdt_ref[0, 0, 0]                       # (Q, P)
     bm = bm_ref[0, 0]                            # (Q, N)
     cm = cm_ref[0, 0]                            # (Q, N)
-    cum = cum_ref[0, 0, 0]                       # (Q,)
+    cum = cum_col_ref[0, 0, 0]                   # (Q, 1)
+    cum_t = cum_row_ref[0, 0, 0]                 # (1, Q)
 
     # intra-chunk quadratic form: L[i,j] = exp(cum_i - cum_j) for i >= j
-    diff = cum[:, None] - cum[None, :]           # (Q, Q), <= 0 on tril
+    diff = cum - cum_t                           # (Q, Q), <= 0 on tril
     mask = jax.lax.broadcasted_iota(jnp.int32, (q_len, q_len), 0) >= \
         jax.lax.broadcasted_iota(jnp.int32, (q_len, q_len), 1)
     decay = jnp.where(mask, jnp.exp(diff), 0.0)
@@ -60,13 +59,16 @@ def _ssd_kernel(xdt_ref, bm_ref, cm_ref, cum_ref, y_ref, h_ref, *,
     h = h_ref[...]
     y_inter = jax.lax.dot_general(cm, h, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
-    y_inter = y_inter * jnp.exp(cum)[:, None]    # (Q, P)
+    y_inter = y_inter * jnp.exp(cum)             # (Q, P)
     y_ref[0, 0, 0] = (y_intra + y_inter).astype(y_ref.dtype)
 
     # state update: h' = exp(total) * h + (xdt * sd)^T @ bm
-    total = cum[q_len - 1]
-    sd = jnp.exp(total - cum)                    # (Q,)
-    contrib = jax.lax.dot_general(xdt * sd[:, None], bm,
+    # cum at the chunk's last position, as a (1, 1) reduction (a slice
+    # at lane Q-1 gets a layout Mosaic cannot broadcast)
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, q_len), 1) == q_len - 1
+    total = jnp.sum(jnp.where(last, cum_t, 0.0), axis=1, keepdims=True)
+    sd = jnp.exp(total - cum)                    # (Q, 1)
+    contrib = jax.lax.dot_general(xdt * sd, bm,
                                   (((0,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
     h_ref[...] = jnp.exp(total) * h + contrib    # (P, N)
@@ -83,6 +85,10 @@ def ssd_chunk_scan_pallas(xdt: jax.Array, bm: jax.Array, cm: jax.Array,
     if cum.shape != (B, H, C, Q):
         raise ValueError(f"bad cum shape: {cum.shape}")
     kernel = functools.partial(_ssd_kernel, q_len=Q)
+    # cum rides in twice, as a column and as a row: a (.., 1, Q) or
+    # (.., Q, 1) block matches its array's last two dims, which Mosaic
+    # requires, and the kernel then needs no in-VMEM transpose
+    cum = cum.astype(jnp.float32)
     return pl.pallas_call(
         kernel,
         grid=(B, H, C),
@@ -90,17 +96,18 @@ def ssd_chunk_scan_pallas(xdt: jax.Array, bm: jax.Array, cm: jax.Array,
             pl.BlockSpec((1, 1, 1, Q, P), lambda b, h, c: (b, h, c, 0, 0)),
             pl.BlockSpec((1, 1, Q, N), lambda b, h, c: (b, c, 0, 0)),
             pl.BlockSpec((1, 1, Q, N), lambda b, h, c: (b, c, 0, 0)),
-            pl.BlockSpec((1, 1, 1, Q), lambda b, h, c: (b, h, c, 0)),
+            pl.BlockSpec((1, 1, 1, Q, 1), lambda b, h, c: (b, h, c, 0, 0)),
+            pl.BlockSpec((1, 1, 1, 1, Q), lambda b, h, c: (b, h, c, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, 1, Q, P),
                                lambda b, h, c: (b, h, c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct(xdt.shape, jnp.float32),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(xdt.astype(jnp.float32), bm.astype(jnp.float32),
-      cm.astype(jnp.float32), cum.astype(jnp.float32))
+      cm.astype(jnp.float32), cum[..., None], cum[..., None, :])
 
 
 def flops(B: int, H: int, S: int, Q: int, P: int, N: int) -> float:

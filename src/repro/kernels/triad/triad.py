@@ -17,8 +17,7 @@ import functools
 
 import jax
 from jax.experimental import pallas as pl
-
-from ...compat import tpu_compiler_params
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 1024  # elements per row: one (8, 128) f32 vreg tile
 
@@ -49,7 +48,7 @@ def triad_pallas(a: jax.Array, b: jax.Array, gamma: float, *, br: int = 256,
                   pl.BlockSpec((br, lanes), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, lanes), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(a, b)

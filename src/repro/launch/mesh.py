@@ -14,13 +14,12 @@ from __future__ import annotations
 
 import jax
 
-from ..compat import make_mesh
-
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
@@ -28,4 +27,5 @@ def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
     the CPU training example)."""
     n = len(jax.devices())
     model = max(1, min(model, n))
-    return make_mesh((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 
 from ..distributed.actctx import shard_act
 from ..kernels.flash_attention import flash_attention
+from ..kernels.platform import interpret_mode
 from .config import ModelConfig
 from .params import ParamDef
 
@@ -165,7 +166,8 @@ def attention_full(p: dict, x: jax.Array, cfg: ModelConfig, *,
         k = apply_rope(k, pos_k, cfg.rope_theta)
     if use_flash and kv_x is None:
         out = flash_attention(q, k, v, causal=causal, window=window,
-                              bq=block_q, bk=block_k, interpret=True)
+                              bq=block_q, bk=block_k,
+                              interpret=interpret_mode())
     else:
         out = _attend(q, k, v, causal=causal and kv_x is None, window=window)
     return shard_act(jnp.einsum("bhsk,hkd->bsd", out, p["wo"]), ACT_BSD)

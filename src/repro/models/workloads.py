@@ -31,6 +31,7 @@ __all__ = [
     "TINY_CONFIG",
     "WORKLOAD_NAMES",
     "build_workload",
+    "train_step_fn",
     "workload_static_cost",
 ]
 
@@ -83,9 +84,6 @@ class ModelWorkload:
         """Lower + compile once (AOT); callers reuse for text and cost."""
         return self.jit().lower(*self.args).compile()
 
-    def hlo_text(self) -> str:
-        return self.compiled().as_text()
-
 
 def _tiny_shape(kind: str, batch: int, seq: int) -> WorkloadShape:
     return WorkloadShape(name=f"tiny_{kind}", seq_len=seq,
@@ -129,24 +127,29 @@ def _model_batch(cfg: ModelConfig, shape: WorkloadShape) -> dict:
     return batch
 
 
-def _build_train(cfg: ModelConfig, step: StepConfig,
-                 batch_size: int, seq: int) -> ModelWorkload:
+def train_step_fn(cfg: ModelConfig, step: StepConfig) -> Callable:
+    """``(params, batch) -> (loss, grads)`` of one training step; takes
+    arrays or, to compile without allocating, ``ShapeDtypeStruct``s."""
     import jax
 
     from . import api
-
-    shape = _tiny_shape("train", batch_size, seq)
-    params = _materialized(cfg)
-    batch = _model_batch(cfg, shape)
 
     def train_step(params, batch):
         loss, grads = jax.value_and_grad(
             lambda p: api.loss_fn(p, batch, cfg, step))(params)
         return loss, grads
 
-    return ModelWorkload(name="train_step", kind="train", fn=train_step,
-                         args=(params, batch), cfg=cfg, step=step,
-                         shape=shape)
+    return train_step
+
+
+def _build_train(cfg: ModelConfig, step: StepConfig,
+                 batch_size: int, seq: int) -> ModelWorkload:
+    shape = _tiny_shape("train", batch_size, seq)
+    params = _materialized(cfg)
+    batch = _model_batch(cfg, shape)
+    return ModelWorkload(name="train_step", kind="train",
+                         fn=train_step_fn(cfg, step), args=(params, batch),
+                         cfg=cfg, step=step, shape=shape)
 
 
 def _build_prefill(cfg: ModelConfig, step: StepConfig,
@@ -203,7 +206,7 @@ def _build_dgemm(m: int, n: int, k: int) -> ModelWorkload:
                          declared_flops=2.0 * m * n * k)
 
 
-def build_workload(name: str, arch: Optional[str] = None, *,
+def build_workload(name: str, arch: "str | ModelConfig | None" = None, *,
                    step: Optional[StepConfig] = None,
                    batch_size: int = _TINY_BATCH, seq_len: int = _TINY_SEQ,
                    m: int = 128, n: int = 128, k: int = 128,
@@ -211,8 +214,10 @@ def build_workload(name: str, arch: Optional[str] = None, *,
     """Build one named workload with concrete inputs.
 
     ``arch`` selects a smoke-scale architecture from ``repro.configs``
-    (e.g. ``"mixtral_8x22b"`` → its SMOKE config); the default is the
-    in-module :data:`TINY_CONFIG` dense toy. ``step`` carries the
+    by name (e.g. ``"mixtral_8x22b"`` → its SMOKE config), or is a
+    :class:`ModelConfig` built as given (``repro.configs.get(name)`` is
+    the published size); the default is the in-module
+    :data:`TINY_CONFIG` dense toy. ``step`` carries the
     execution knobs — including the Pallas flash-attention tile sizes —
     so a tuner can rebuild the same workload under different configs.
     """
@@ -223,6 +228,8 @@ def build_workload(name: str, arch: Optional[str] = None, *,
         return _build_dgemm(m, n, k)
     if arch is None:
         cfg = TINY_CONFIG
+    elif isinstance(arch, ModelConfig):
+        cfg = arch
     else:
         from repro.configs import get_smoke
 

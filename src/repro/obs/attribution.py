@@ -16,7 +16,8 @@ Two modes, mirroring :mod:`repro.obs.device_timing`:
   achieved FLOP/s (or B/s for flop-free ops) against the attainable
   roof at the op's intensity, and the remainder is the device time no
   HLO op claimed (trace overhead, unmatched events).
-- **static** — no device tracks (CPU backends emit none): per-op time
+- **static** — ``force_static``, or the CPU backend (whose profiler
+  emits no device tracks; on an accelerator that raises): per-op time
   is *modeled* as ``max(flops/F_p, bytes/B_a)`` — the roofline's own
   lower bound — subsystem shares come from the model, ``%-of-roof`` is
   100 by construction, and the remainder is exactly zero. Every op
@@ -174,6 +175,7 @@ class AttributionReport:
     roofs: Optional[Roofs]
     unhandled: dict[str, int]            # op kinds the cost model skipped
     fingerprint: str = ""
+    host_time_s: Optional[float] = None  # host bracket (measured mode)
 
     @property
     def unattributed_frac(self) -> float:
@@ -199,6 +201,7 @@ class AttributionReport:
             "total_flops": self.total_flops,
             "total_bytes": self.total_bytes,
             "device_total_s": self.device_total_s,
+            "host_time_s": self.host_time_s,
             "attributed_s": self.attributed_s,
             "unattributed_s": self.unattributed_s,
             "unattributed_frac": self.unattributed_frac,
@@ -340,7 +343,8 @@ def _attribution_from_device(workload_name: str, module: ModuleOps,
         device_total_s=device.total_s, attributed_s=joined,
         unattributed_s=max(device.total_s - joined, 0.0),
         subsystem_seconds=_subsystem_seconds(attributed), roofs=roofs,
-        unhandled=dict(module.unhandled), fingerprint=fingerprint)
+        unhandled=dict(module.unhandled), fingerprint=fingerprint,
+        host_time_s=device.host_time_s)
 
 
 def attribute(workload, roofs: Optional[Roofs] = None, *,
@@ -350,25 +354,24 @@ def attribute(workload, roofs: Optional[Roofs] = None, *,
 
     Tries the measured path (one profiled invocation, like
     :func:`repro.obs.device_timing.profile_sample`) unless
-    ``force_static``; degrades to static HLO-only attribution when the
-    profiler yields no device tracks. Emits PR-9 trace instants so the
-    Perfetto export carries op-level context.
+    ``force_static``; on the CPU backend, whose profiler yields no
+    device tracks, it degrades to static HLO-only attribution, and on an
+    accelerator a profile without device tracks raises. Emits trace
+    instants so the Perfetto export carries op-level context.
     """
     from repro.core.profiling import trace_instant
 
-    module = parse_hlo_ops(workload.hlo_text())
-    fingerprint = ""
-    try:
-        from repro.core.cache import hardware_fingerprint
+    from repro.core.cache import hardware_fingerprint
 
-        fingerprint = hardware_fingerprint()
-    except Exception:
-        pass
+    compiled = workload.compiled()
+    module = parse_hlo_ops(compiled.as_text())
+    fingerprint = hardware_fingerprint()
     device = None
     if not force_static:
         from .device_timing import profile_ops
 
-        compiled = workload.compiled()
+        # raises on an accelerator when the trace has no device track, so
+        # only the CPU backend ever reaches the static fallback below
         device = profile_ops(lambda: compiled(*workload.args),
                              log_dir=log_dir)
     if device is None:

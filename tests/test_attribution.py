@@ -390,9 +390,11 @@ def test_render_html_threads_max_trial_rows():
 def test_cli_attribute_static(tmp_path):
     out_json = tmp_path / "attr.json"
     out_html = tmp_path / "dash.html"
+    cache = tmp_path / "roofs.jsonl"
+    _seed_cache(cache)    # explicit roofs: this CPU has no published peaks
     proc = subprocess.run(
         [sys.executable, str(REPO / "scripts" / "roofline_report.py"),
-         "--attribute", "dgemm", "--static",
+         str(cache), "--attribute", "dgemm", "--static",
          "--attribution-json", str(out_json), "--html", str(out_html),
          "--max-trial-rows", "5"],
         capture_output=True, text=True, timeout=600,
@@ -406,3 +408,17 @@ def test_cli_attribute_static(tmp_path):
     assert all(op["subsystem"] for op in doc["ops"])
     assert all(op["pct_of_roof"] is not None for op in doc["ops"])
     assert "Attribution —" in out_html.read_text(encoding="utf-8")
+
+
+def test_cli_attribute_without_roofs_needs_published_peaks():
+    """No cache: the peaks come from the device the workload runs on,
+    and the CPU is not in the published-peaks table — an error, never a
+    silent default."""
+    proc = subprocess.run(
+        [sys.executable, str(REPO / "scripts" / "roofline_report.py"),
+         "--attribute", "dgemm", "--static"],
+        capture_output=True, text=True, timeout=600,
+        env={**__import__("os").environ, "PYTHONPATH": str(REPO / "src"),
+             "JAX_PLATFORMS": "cpu"})
+    assert proc.returncode == 2
+    assert "no published peaks for device kind 'cpu'" in proc.stderr

@@ -129,6 +129,62 @@ def test_dot_flops_are_2mnk():
     assert cost.unhandled == {}
 
 
+def test_bare_operand_names_resolve_through_symbol_table():
+    # newer XLA prints operands as bare names; their shapes come from the
+    # computation's own definitions
+    bare = DOT_MODULE.replace("f32[64,32]{1,0} %a,", "%a,") \
+        .replace("f32[32,48]{1,0} %b)", "%b)")
+    assert "dot(%a, %b)" in bare
+    for parsed in (parse_hlo_cost(bare), parse_hlo_ops(bare)):
+        assert parsed.flops == 2 * 64 * 48 * 32
+        assert parsed.bytes_accessed == (64 * 32 + 32 * 48 + 64 * 48) * 4
+
+
+def test_symbol_table_is_per_computation():
+    text = textwrap.dedent("""\
+        %other (x: f32[1000]) -> f32[1000] {
+          %x = f32[1000]{0} parameter(0)
+          ROOT %n = f32[1000]{0} negate(%x)
+        }
+
+        ENTRY %main (x: f32[8]) -> f32[8] {
+          %x = f32[8]{0} parameter(0)
+          ROOT %n.1 = f32[8]{0} negate(%x)
+        }
+    """)
+    ops = parse_hlo_ops(text)
+    assert ops.by_name()["n.1"].bytes_accessed == (8 + 8) * 4
+
+
+# TPU-compiled text: tiled layouts nest parens inside shapes, matmuls are
+# convolutions, and a scanned loop is a tuple-shaped while
+TPU_MODULE = textwrap.dedent("""\
+    %body (p: (s32[], f32[64,32])) -> (s32[], f32[64,32]) {
+      %p = (s32[]{:T(128)}, f32[64,32]{1,0:T(8,128)}) parameter(0)
+      ROOT %t = (s32[]{:T(128)}, f32[64,32]{1,0:T(8,128)}) tuple(%p)
+    }
+
+    ENTRY %main.4 (a.1: f32[64,32], b.1: f32[32,48]) -> f32[64,48] {
+      %a.1 = f32[64,32]{1,0:T(8,128)} parameter(0)
+      %b.1 = f32[32,48]{1,0:T(8,128)} parameter(1)
+      %convolution.5 = f32[64,48]{1,0:T(8,128)S(1)} convolution(%a.1, %b.1), dim_labels=bf_io->bf, metadata={op_name="jit(f)/dot_general"}
+      %copy-start.1 = (f32[64,32]{1,0:T(8,128)S(1)}, f32[64,32]{1,0:T(8,128)}, u32[]{:S(2)}) copy-start(%a.1)
+      %tuple.13 = (s32[]{:T(128)}, f32[64,32]{1,0:T(8,128)}) tuple(%a.1)
+      ROOT %while = (s32[]{:T(128)}, f32[64,32]{1,0:T(8,128)}) while(%tuple.13), condition=%cond, body=%body
+    }
+""")
+
+
+def test_tpu_layouts_tuples_and_convolution_matmuls():
+    ops = parse_hlo_ops(TPU_MODULE).by_name()
+    assert {"convolution.5", "copy-start.1", "while"} <= set(ops)
+    conv = ops["convolution.5"]
+    assert conv.flops == 2 * 64 * 48 * 32
+    assert conv.bytes_accessed == (64 * 32 + 32 * 48 + 64 * 48) * 4
+    assert parse_hlo_cost(TPU_MODULE).flops_by_op == {
+        "convolution": 2 * 64 * 48 * 32}
+
+
 def test_elementwise_one_flop_per_element():
     text = "  %add = f32[128]{0} add(f32[128]{0} %x, f32[128]{0} %y)\n"
     cost = parse_hlo_cost(text)

@@ -276,6 +276,53 @@ def test_device_timing_degrades_gracefully():
     assert out is None or isinstance(out, DeviceTiming)
 
 
+def test_device_busy_is_the_union_of_the_ops_line():
+    """A TPU v5e trace of a 4-step scanned matmul (recorded on the chip):
+    the device shows the program on "XLA Modules" and its ops on "XLA
+    Ops", where the while op also spans its body ops. Busy time counts
+    each instant once; summing every event would triple it."""
+    import json
+
+    from repro.obs.device_timing import parse_device_ops, union_length
+
+    doc = json.loads((REPO / "tests" / "data" / "tpu_v5e_scan_trace.json")
+                     .read_text())
+    ops = parse_device_ops(doc["traceEvents"])
+    device = [e for e in doc["traceEvents"]
+              if e["ph"] == "X" and e["pid"] == 3]
+    summed_us = sum(e["dur"] for e in device)
+    module_us = next(e["dur"] for e in device if e["tid"] == 2)
+    assert summed_us > 2.9 * module_us
+    assert ops.total_s == pytest.approx(union_length(
+        [(e["ts"], e["ts"] + e["dur"]) for e in device if e["tid"] == 3])
+        * 1e-6)
+    assert ops.total_s <= module_us * 1e-6
+    assert ops.total_s > 0.99 * module_us * 1e-6
+    assert ops.n_events == 13                  # the ops line only
+    assert ops.by_name["while"] == pytest.approx(3610.96e-6)
+    assert ops.by_name["fusion.8"] == pytest.approx(
+        4 * 800.436e-6, rel=1e-3)               # four trips, summed
+
+
+def test_union_length():
+    from repro.obs.device_timing import union_length
+
+    assert union_length([]) == 0.0
+    assert union_length([(0, 2), (1, 3), (5, 6)]) == 4.0
+    assert union_length([(0, 10), (2, 3)]) == 10.0
+
+
+def test_profile_raises_on_accelerator_without_device_track(monkeypatch,
+                                                            tmp_path):
+    import jax
+
+    from repro.obs import device_timing
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="no device track"):
+        device_timing.profile_ops(lambda: sum(range(100)), log_dir=tmp_path)
+
+
 def test_evaluator_emits_device_timing_instant(tmp_path):
     settings = dataclasses.replace(SETTINGS, device_timing=True)
     with TraceRecorder(tmp_path / "d.jsonl") as rec:

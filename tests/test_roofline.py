@@ -2,11 +2,12 @@
 
 import math
 
+import pytest
 
 from repro.analysis.hlo import parse_collectives
 from repro.core.roofline import (TPU_V5E, TRIAD_INTENSITY, attainable,
                                  from_measurements, operational_intensity,
-                                 ridge_point)
+                                 published_peaks, ridge_point)
 
 
 def test_attainable_eq2():
@@ -21,6 +22,15 @@ def test_ridge_point():
     # v5e bf16 ridge: 197e12 / 819e9 ≈ 240 FLOP/byte
     assert 230 < ridge_point(TPU_V5E.peak_flops,
                              TPU_V5E.mem_bandwidths["hbm"]) < 250
+
+
+def test_published_peaks_keyed_by_device_kind():
+    v5e = published_peaks("TPU v5 lite")
+    assert v5e is TPU_V5E
+    assert (v5e.peak_flops, v5e.peak_int8_ops) == (197e12, 393e12)
+    assert v5e.mem_bandwidths == {"hbm": 819e9}
+    with pytest.raises(KeyError, match="no published peaks.*'cpu'"):
+        published_peaks("cpu")
 
 
 def test_triad_intensity():
