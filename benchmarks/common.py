@@ -17,7 +17,7 @@ import numpy as np
 
 from repro.core import (Direction, EvaluationSettings, SearchSpace,
                         default_cache, grid, steady_sampler, timed_sampler)
-from repro.core.profiling import trace_instant
+from repro.core.profiling import phase, trace_instant
 from repro.core.searchspace import doubling_from, powers_of_two
 from repro.lint import WorkloadSpec
 
@@ -120,14 +120,17 @@ def dgemm_invocation_factory(n: int, m: int, k: int,
     def factory():
         seed = (n * 1_000_003 + m * 10_007 + k * 101
                 + next(invocation)) % (2 ** 31)
-        if reuse_data and state["data"] is not None:
-            a, b = state["data"]
-        else:
-            a, b = _dgemm_data(n, m, k, seed, dtype)
-            if reuse_data:
-                state["data"] = (a, b)
+        with phase("operands"):
+            if reuse_data and state["data"] is not None:
+                a, b = state["data"]
+            else:
+                a, b = jax.block_until_ready(
+                    _dgemm_data(n, m, k, seed, dtype))
+                if reuse_data:
+                    state["data"] = (a, b)
         f = cache.compile(jnp.dot, (a, b))
-        jax.block_until_ready(f(a, b))      # pre-heat
+        with phase("preheat"):
+            jax.block_until_ready(f(a, b))
         trace_instant("workload", kernel="dgemm", n=n, m=m, k=k,
                       flops=flops, dtype=str(jnp.dtype(dtype)))
         if sampler == "steady":
@@ -153,11 +156,14 @@ def triad_invocation_factory(n_bytes: int, dtype=jnp.float32, *,
     cache = exec_cache if exec_cache is not None else default_cache()
 
     def factory():
-        key = jax.random.key(n % (2 ** 31))
-        a = jax.random.normal(jax.random.fold_in(key, 1), (n,), dtype)
-        b = jax.random.normal(jax.random.fold_in(key, 2), (n,), dtype)
+        with phase("operands"):
+            key = jax.random.key(n % (2 ** 31))
+            a = jax.random.normal(jax.random.fold_in(key, 1), (n,), dtype)
+            b = jax.random.normal(jax.random.fold_in(key, 2), (n,), dtype)
+            jax.block_until_ready((a, b))
         f = cache.compile(triad_kernel, (a, b))
-        jax.block_until_ready(f(a, b))
+        with phase("preheat"):
+            jax.block_until_ready(f(a, b))
         trace_instant("workload", kernel="triad", n=n, bytes=moved,
                       dtype=str(jnp.dtype(dtype)))
 
@@ -452,7 +458,8 @@ def model_step_family(workload: str, arch=None, *,
             if state["compiled"] is None:
                 state["compiled"] = w.compiled()
             f = state["compiled"]
-            jax.block_until_ready(f(*w.args))   # pre-heat
+            with phase("preheat"):
+                jax.block_until_ready(f(*w.args))
             trace_instant("workload", kernel=workload,
                           arch=arch_name, flops=flops,
                           **{k: cfg[k] for k in sorted(cfg)})

@@ -20,8 +20,8 @@ from .executor import (Batch, BatchStats, ExecutionBackend, ExecutionStats,
                        IncumbentCell, ProcessPoolBackend, SerialBackend,
                        SimulatedShardedBackend, ThreadPoolBackend,
                        TrialOutcome)
-from .profiling import (PhaseProfiler, PhaseStats, phase, profiler,
-                        record_phase, trace_instant, trace_sink, trace_span)
+from .profiling import (PhaseProfiler, PhaseStats, compiling, phase,
+                        profiler, trace_instant, trace_sink, trace_span)
 from .report import (FingerprintReport, IncumbentTrial, build_reports,
                      dgemm_config_intensity, extract_incumbent,
                      group_by_fingerprint, pooled_state, render_csv,
@@ -57,8 +57,8 @@ __all__ = [
     "TimingResolutionWarning", "calibrate_batch", "calibrate_clock",
     "steady_sampler", "timed_sampler",
     "CompilePipeline", "ExecCacheStats", "ExecutableCache", "default_cache",
-    "PhaseProfiler", "PhaseStats", "phase", "profiler",
-    "record_phase", "trace_instant", "trace_sink", "trace_span",
+    "PhaseProfiler", "PhaseStats", "compiling", "phase", "profiler",
+    "trace_instant", "trace_sink", "trace_span",
     "Batch", "BatchStats", "ExecutionBackend", "ExecutionStats",
     "IncumbentCell", "ProcessPoolBackend", "SerialBackend",
     "SimulatedShardedBackend", "ThreadPoolBackend", "TrialOutcome",

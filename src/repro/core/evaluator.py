@@ -23,8 +23,7 @@ import warnings
 from typing import Any, Callable, Optional, Sequence, Union
 
 from . import welford
-from .profiling import (phase, record_phase, trace_instant, trace_sink,
-                        trace_span)
+from .profiling import phase, trace_span
 from .stop_conditions import (CIConverged, Direction, EvalContext, MaxCount,
                               MaxTime, StopCondition, StopDecision,
                               UpperBoundPrune, first_decision)
@@ -98,12 +97,6 @@ class EvaluationSettings:
     ci_method: str = "welford"
     bootstrap_capacity: int = 256
     bootstrap_resamples: int = 200
-    # Opt-in on-device timing (repro.obs.device_timing): when a trace
-    # recorder is installed, trials that beat the incumbent get one extra
-    # profiled invocation whose device-side kernel time and host-vs-device
-    # skew land in the trace. On the CPU backend it degrades to an
-    # "unavailable" instant. Never touches the measured samples.
-    device_timing: bool = False
 
     def label(self) -> str:
         """Technique label as used in the paper's tables, e.g. 'C+I+O'."""
@@ -249,9 +242,6 @@ class Evaluator:
             if inv.pruned:
                 decision = StopDecision(reason="inner_pruned", pruned=True)
                 break
-        if s.device_timing and not pruned:
-            self._device_profile(sample_fn, float(outer_state.mean),
-                                 incumbent, direction)
         return EvalResult(score=float(outer_state.mean),
                           best_invocation=float(best_inv),
                           invocations=tuple(invocations),
@@ -260,28 +250,6 @@ class Evaluator:
                           measured_time_s=measured,
                           pruned=pruned,
                           stop_reason=decision.reason)
-
-    # -- on-device timing -----------------------------------------------------
-    def _device_profile(self, sample_fn: Callable[[], float], score: float,
-                        incumbent: Incumbent, direction: Direction) -> None:
-        """One extra profiled invocation for incumbent-candidate trials.
-
-        Only runs when a trace recorder is installed (the result is a
-        trace attribute, nothing else consumes it) and only for scores
-        that beat the current incumbent — profiling slows the profiled
-        call, so doomed configurations never pay for it.
-        """
-        if trace_sink() is None:
-            return
-        inc = _resolve_incumbent(incumbent)
-        if inc is not None and not direction.better(score, inc):
-            return
-        from repro.obs.device_timing import profile_sample
-        timing = profile_sample(sample_fn)   # raises on an accelerator
-        if timing is None:
-            trace_instant("device_timing_unavailable")
-        else:
-            trace_instant("device_timing", **timing.to_json())
 
 
 class TimingResolutionWarning(UserWarning):
@@ -367,14 +335,14 @@ def timed_sampler(fn: Callable[[], None], work: float,
     resolution = calibration.resolution_s if calibration else 0.0
     floor = resolution if resolution > 0.0 else 1e-12
     warned = [False]
-    # clock readings only mark trace positions when they share the
-    # recorder's clock; fake test clocks fall back to "now"
-    default_clock = clock is time.perf_counter
 
     def sample() -> float:
-        t0 = clock()
-        fn()
-        t1 = clock()
+        # the span opens and closes outside the clock readings, so its
+        # cost never lands in a sample
+        with phase("dispatch"):
+            t0 = clock()
+            fn()
+            t1 = clock()
         dt = t1 - t0 - overhead
         if dt < 10.0 * resolution and not warned[0]:
             warned[0] = True
@@ -384,8 +352,6 @@ def timed_sampler(fn: Callable[[], None], work: float,
                 f"larger per-call workload", TimingResolutionWarning,
                 stacklevel=2)
         dt = max(dt, floor)
-        record_phase("dispatch", t1 - t0,
-                     at=t1 if default_clock else None)
         return work / dt
 
     return sample
@@ -485,21 +451,21 @@ def steady_sampler(dispatch: Callable[[], Any], work: float, *,
     clock_overhead = 2.0 * calibration.overhead_s if calibration else 0.0
     total_work = work * batch
     b = batch
-    default_clock = clock is time.perf_counter
 
     def sample() -> float:
-        t0 = clock()
-        h = None
-        for _ in range(b):
-            h = dispatch()
-        tm = clock()
-        sync(h)
-        t1 = clock()
-        dt = max(t1 - t0 - clock_overhead, 1e-12)
-        record_phase("dispatch", tm - t0,
-                     at=tm if default_clock else None)
-        record_phase("sync", t1 - tm,
-                     at=t1 if default_clock else None)
+        # each span opens and closes outside its clock readings; the few
+        # instructions between the two brackets are left out of the reading
+        with phase("dispatch"):
+            t0 = clock()
+            h = None
+            for _ in range(b):
+                h = dispatch()
+            tm = clock()
+        with phase("sync"):
+            ts = clock()
+            sync(h)
+            t1 = clock()
+        dt = max(tm - t0 + t1 - ts - clock_overhead, 1e-12)
         return total_work / dt
 
     sample.batch = batch

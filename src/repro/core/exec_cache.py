@@ -36,7 +36,7 @@ from collections import OrderedDict
 from pathlib import Path
 from typing import Any, Callable, Mapping, Optional, Sequence
 
-from .profiling import phase, trace_instant
+from .profiling import compile_thread, compiling, trace_instant
 
 __all__ = ["COMPILE_CACHE_DIR", "CompilePipeline", "ExecCacheStats",
            "ExecutableCache", "default_cache", "enable_compile_cache"]
@@ -193,7 +193,7 @@ class ExecutableCache:
                 raise entry.error
             return entry.executable
         try:
-            with phase("compile"):
+            with compiling("exec_cache"):
                 t0 = time.perf_counter()
                 entry.executable = self._lower_and_compile(fn, args, static)
                 dt = time.perf_counter() - t0
@@ -324,6 +324,7 @@ class CompilePipeline:
             self._thread.start()
 
     def _run(self) -> None:
+        compile_thread("pipeline")
         while True:
             with self._cv:
                 while not self._queue and not self._closed:

@@ -6,12 +6,17 @@ you cannot see. This module is the minimal instrumentation layer the
 harness self-benchmark (``scripts/bench_harness.py``) activates to
 attribute a tuning session's wall clock to phase buckets:
 
-  ``setup``     invocation-factory work (data generation, pre-heat)
-  ``compile``   kernel lowering + compilation (ExecutableCache misses)
-  ``dispatch``  timed kernel work as seen by the samplers
+  ``audit``     the pre-run workload audit (``Tuner._validate_workload``)
+  ``build``     a trial's ``benchmark(config)`` call
+  ``setup``     invocation-factory work, which holds:
+  ``operands``  making an invocation's operands
+  ``preheat``   the untimed first call
+  ``compile``   one lowering + compilation (:func:`compiling`)
+  ``dispatch``  timed kernel work, inside the samplers' clock readings
   ``sync``      device synchronization at the end of a batched sample
   ``stats``     Welford updates + stop-condition evaluation
   ``cache_io``  trial-cache JSONL appends
+  ``ledger_io`` the run-ledger append at the end of a session
 
 Buckets may nest (a cache-served ``compile`` happens inside ``setup``);
 each records its own wall time independently, so buckets are a
@@ -20,30 +25,41 @@ non-measured metric from session wall clock and kernel-time references,
 and uses these buckets to explain where the overhead went.
 
 Instrumentation sites call :func:`phase`, which is a no-op (two global
-reads, no allocation) unless a :class:`PhaseProfiler` *or* a trace sink
-is installed — the hot per-sample paths stay hardware-fast when nobody
-is watching.  Thread-safe: concurrent trials on the thread backend fold
+reads, no allocation) unless a :class:`PhaseProfiler` *or* a span
+exporter is installed — the hot per-sample paths stay hardware-fast when
+nobody is watching.  Thread-safe: concurrent trials on the thread backend fold
 into the same buckets under a lock.
 
-The module is also the **dual-sink seam** for ``repro.obs``: a
-:class:`~repro.obs.trace.TraceRecorder` installs itself via
-:func:`set_trace_sink`, after which every :func:`phase` site feeds both
-the aggregate buckets (when a profiler is active) and a per-thread span
-in the trace — per-trial attribution the folded buckets cannot give.
-Core modules never import ``repro.obs``; they call the sink-agnostic
-helpers here (:func:`trace_span`, :func:`trace_instant`,
-:func:`record_phase`), which no-op when no recorder is installed.
+The module is also the **span seam** for ``repro.obs`` and for the JAX
+profiler. Every :func:`phase` and :func:`trace_span` site feeds whichever
+span exporters are installed:
+
+* a :class:`~repro.obs.trace.TraceRecorder` (installed via
+  :func:`set_trace_sink`) writes each span to its JSONL file, with
+  parent links — per-trial attribution the folded buckets cannot give;
+* while the JAX profiler is collecting, :func:`profiler_spans` (entered
+  once per ``Tuner.tune()``) turns each span into a
+  ``jax.profiler.TraceAnnotation`` named ``repro.<name>``, on the
+  profiler's own clock beside the device's ops. Its args carry the
+  enclosing trial's index and config label, so spans of one trial share
+  them.
+
+Instants go to the recorder only. Core modules never import
+``repro.obs``; they call the sink-agnostic helpers here
+(:func:`trace_span`, :func:`trace_instant`, :func:`compiling`), which
+no-op when nothing is installed.
 """
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from typing import Callable, Optional
 
-__all__ = ["PhaseProfiler", "PhaseStats", "phase", "profiler",
-           "record_phase", "set_trace_sink", "trace_instant", "trace_sink",
-           "trace_span"]
+__all__ = ["PROFILER_PREFIX", "PhaseProfiler", "PhaseStats", "compile_thread",
+           "compiling", "phase", "profiler", "profiler_spans",
+           "set_trace_sink", "trace_instant", "trace_sink", "trace_span"]
 
 
 class PhaseStats:
@@ -156,9 +172,20 @@ class PhaseProfiler:
 _INSTALL_LOCK = threading.Lock()
 _ACTIVE: Optional[PhaseProfiler] = None
 
+#: prefix of every span name on the profiler's trace, so that no
+#: program span is ever read as one of a caller's own annotations
+PROFILER_PREFIX = "repro."
+
+# the span sink every phase()/trace_span() site feeds: the recorder, the
+# profiler exporter, both, or None; rebuilt by _install() whenever one
+# of the two below changes, so the sites read one global
+_TRACE = None
 # the installed TraceRecorder (repro.obs.trace), or None; duck-typed so
 # this module never has to import obs
-_TRACE = None
+_RECORDER = None
+# the profiler exporter, and how many tune() calls hold it
+_EXPORTER = None
+_EXPORTS = 0
 
 
 def profiler() -> Optional[PhaseProfiler]:
@@ -166,33 +193,187 @@ def profiler() -> Optional[PhaseProfiler]:
     return _ACTIVE
 
 
-def set_trace_sink(sink) -> None:
-    """Install/clear the trace sink (called by ``TraceRecorder``)."""
+def _install() -> None:
     global _TRACE
-    _TRACE = sink
+    rec, exp = _RECORDER, _EXPORTER
+    _TRACE = (rec if exp is None else exp if rec is None
+              else _BothSinks(rec, exp))
+
+
+def set_trace_sink(sink) -> None:
+    """Install/clear the trace recorder (called by ``TraceRecorder``)."""
+    global _RECORDER
+    with _INSTALL_LOCK:
+        _RECORDER = sink
+        _install()
 
 
 def trace_sink():
-    """The installed trace sink, or ``None`` when tracing is off."""
-    return _TRACE
+    """The installed trace recorder, or ``None`` when it is off."""
+    return _RECORDER
+
+
+def _arg(value):
+    """A span attribute as a ``TraceAnnotation`` arg. The profiler packs
+    args into the event name as ``name#k=v,k=v#``, so a value holds no
+    ``,`` or ``#``: a config becomes ``k=v k=v``."""
+    if isinstance(value, (bool, int, float)):
+        return value
+    if isinstance(value, dict):
+        return " ".join(f"{k}={value[k]}" for k in sorted(value))
+    return str(value).replace(",", " ").replace("#", " ")
+
+
+class _ProfilerSpan:
+    """One span as a ``jax.profiler.TraceAnnotation``; its args carry the
+    enclosing trial's ``trial`` index and ``config`` label."""
+
+    __slots__ = ("_spans", "_name", "_cat", "_attrs", "_annotation")
+
+    def __init__(self, spans: "_ProfilerSpans", name: str, cat: str,
+                 attrs: dict):
+        self._spans = spans
+        self._name = name
+        self._cat = cat
+        self._attrs = attrs
+        self._annotation = None
+
+    def __enter__(self):
+        stack = self._spans.stack()
+        tags = stack[-1] if stack else {}
+        attrs = self._attrs
+        if self._cat == "trial":
+            tags = {"trial": attrs.get("index")}
+        if "config" in attrs:
+            tags = {**tags, "config": _arg(attrs["config"])}
+        stack.append(tags)
+        args = {k: _arg(v) for k, v in attrs.items()}
+        args.update(tags)
+        self._annotation = self._spans.annotation(
+            f"{PROFILER_PREFIX}{self._name}", **args)
+        self._annotation.__enter__()
+        return self
+
+    def set(self, **attrs):
+        self._annotation.set_metadata(
+            **{k: _arg(v) for k, v in attrs.items()})
+
+    def __exit__(self, *exc):
+        self._annotation.__exit__(*exc)
+        self._spans.stack().pop()
+        return False
+
+
+class _ProfilerSpans:
+    """Span exporter onto the JAX profiler's host line. Instants are not
+    exported."""
+
+    def __init__(self, annotation):
+        self.annotation = annotation
+        self._local = threading.local()
+
+    def stack(self) -> list:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def span(self, name: str, cat: str = "phase", *, context: bool = False,
+             **attrs) -> _ProfilerSpan:
+        return _ProfilerSpan(self, name, cat, attrs)
+
+    def instant(self, name: str, **attrs) -> None:
+        return None
+
+
+class _SpanPair:
+    """One span open on two exporters."""
+
+    __slots__ = ("_first", "_second")
+
+    def __init__(self, first, second):
+        self._first = first
+        self._second = second
+
+    def __enter__(self):
+        self._first.__enter__()
+        self._second.__enter__()
+        return self
+
+    def set(self, **attrs):
+        self._first.set(**attrs)
+        self._second.set(**attrs)
+
+    def __exit__(self, *exc):
+        self._second.__exit__(*exc)
+        self._first.__exit__(*exc)
+        return False
+
+
+class _BothSinks:
+    """The recorder and the profiler exporter fed from one site."""
+
+    __slots__ = ("_recorder", "_exporter")
+
+    def __init__(self, recorder, exporter: _ProfilerSpans):
+        self._recorder = recorder
+        self._exporter = exporter
+
+    def span(self, name: str, cat: str = "phase", *, context: bool = False,
+             **attrs) -> _SpanPair:
+        return _SpanPair(
+            self._recorder.span(name, cat=cat, context=context, **attrs),
+            self._exporter.span(name, cat=cat, **attrs))
+
+    def instant(self, name: str, **attrs) -> None:
+        self._recorder.instant(name, **attrs)
+
+
+@contextlib.contextmanager
+def profiler_spans():
+    """Export every span to the JAX profiler while this block runs, when
+    the profiler is collecting as it is entered (``Tuner.tune`` enters it
+    once per call); otherwise install nothing."""
+    global _EXPORTER, _EXPORTS
+    import jax
+
+    if not jax.profiler.TraceAnnotation.is_enabled():
+        yield
+        return
+    with _INSTALL_LOCK:
+        _EXPORTS += 1
+        if _EXPORTER is None:
+            _EXPORTER = _ProfilerSpans(jax.profiler.TraceAnnotation)
+            _install()
+    try:
+        yield
+    finally:
+        with _INSTALL_LOCK:
+            _EXPORTS -= 1
+            if _EXPORTS == 0:
+                _EXPORTER = None
+                _install()
 
 
 class _DualPhase:
     """One ``phase()`` site feeding bucket and/or span sinks."""
 
-    __slots__ = ("name", "_prof", "_sink", "_span", "_bucket")
+    __slots__ = ("name", "_prof", "_sink", "_span", "_bucket", "_attrs")
 
-    def __init__(self, name: str, prof: Optional[PhaseProfiler], sink):
+    def __init__(self, name: str, prof: Optional[PhaseProfiler], sink,
+                 attrs: Optional[dict] = None):
         self.name = name
         self._prof = prof
         self._sink = sink
         self._span = None
         self._bucket = None
+        self._attrs = attrs or {}
 
     def __enter__(self):
         if self._prof is not None:
             self._bucket = self._prof.phase(self.name).__enter__()
-        self._span = self._sink.span(self.name, cat="phase").__enter__()
+        self._span = self._sink.span(self.name, cat="phase",
+                                     **self._attrs).__enter__()
         return self
 
     def set(self, **attrs):
@@ -208,8 +389,8 @@ class _DualPhase:
 def phase(name: str):
     """Context manager timing one phase span; free when nobody watches.
 
-    Dual-sink: feeds the active :class:`PhaseProfiler` buckets and the
-    active trace sink's span tree, whichever (or both) is installed.
+    Feeds the active :class:`PhaseProfiler` buckets and the installed span
+    exporters, whichever are installed.
     """
     active = _ACTIVE
     sink = _TRACE
@@ -220,26 +401,35 @@ def phase(name: str):
     return _DualPhase(name, active, sink)
 
 
-def record_phase(name: str, seconds: float,
-                 at: Optional[float] = None) -> None:
-    """Record an interval the caller already measured, into both sinks.
+# which caller drives this thread's compiles, where one says so
+_COMPILER = threading.local()
 
-    The samplers use this for their hot-loop deltas (clock readings are
-    already taken; a context manager would add overhead).  ``at`` is the
-    interval's end on ``time.perf_counter`` so adjacent phases land
-    adjacent in the trace; ``None`` means "now".
-    """
-    active = _ACTIVE
-    if active is not None:
-        active.add(name, seconds)
+
+def compile_thread(source: str) -> None:
+    """Label every later compile on this thread with ``source`` (the
+    compile pipeline's worker calls it once)."""
+    _COMPILER.source = source
+
+
+def compiling(source: str):
+    """The ``compile`` phase around one lowering plus compilation, counted
+    in the ``compile.calls`` counter. ``source`` names the call site
+    (``exec_cache``, ``workload``, ``trace_cost``); a compile on the
+    pipeline's worker thread reports ``pipeline`` instead."""
+    from repro.obs.metrics import metrics
+
+    metrics().inc("compile.calls")
     sink = _TRACE
-    if sink is not None:
-        sink.add_phase(name, seconds, at=at)
+    if sink is None:
+        return phase("compile")
+    source = getattr(_COMPILER, "source", source)
+    return _DualPhase("compile", _ACTIVE, sink, {"source": source})
 
 
 def trace_span(name: str, cat: str = "phase", *, context: bool = False,
                **attrs):
-    """Open a span on the trace sink; shared no-op when tracing is off."""
+    """Open a span on the span exporters; shared no-op when none is
+    installed."""
     sink = _TRACE
     if sink is None:
         return _NULL
@@ -247,7 +437,7 @@ def trace_span(name: str, cat: str = "phase", *, context: bool = False,
 
 
 def trace_instant(name: str, **attrs) -> None:
-    """Emit an instant event on the trace sink, if one is installed."""
+    """Emit an instant event on the trace recorder, if one is installed."""
     sink = _TRACE
     if sink is not None:
         sink.instant(name, **attrs)

@@ -24,7 +24,8 @@ from .evaluator import (EvalResult, EvaluationSettings, Evaluator, Incumbent,
 from .exec_cache import CompilePipeline, default_cache
 from .executor import (Batch, BatchStats, ExecutionBackend, IncumbentCell,
                        SerialBackend, TrialOutcome)
-from .profiling import phase, trace_instant, trace_sink, trace_span
+from .profiling import (phase, profiler_spans, trace_instant, trace_sink,
+                        trace_span)
 from .searchspace import Config, SearchSpace
 from .strategy import ExhaustiveStrategy, SearchStrategy, SuccessiveHalvingStrategy
 
@@ -63,7 +64,9 @@ class EvaluateTask:
         from repro.obs.metrics import metrics
         metrics().inc("trials.started")
         evaluator = Evaluator(settings or self.settings, clock=self.clock)
-        return evaluator.evaluate(self.benchmark(config), incumbent=incumbent)
+        with phase("build"):
+            factory = self.benchmark(config)
+        return evaluator.evaluate(factory, incumbent=incumbent)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -186,17 +189,35 @@ class Tuner:
         :class:`CompilePipeline` is used as-is (and left open for the
         caller to close). The cache's in-flight deduplication guarantees
         a trial never compiles what the pipeline already started.
+
+        While the JAX profiler is collecting at entry, every span of the
+        run (audit, trials, compiles, samples, persistence) also lands on
+        the profiler's trace as a ``repro.<name>`` annotation.
         """
+        if validate not in ("off", "warn", "strict"):
+            raise ValueError(f"validate must be 'off', 'warn' or 'strict', "
+                             f"got {validate!r}")
+        with profiler_spans():
+            return self._tune(benchmark, progress, backend, cache,
+                              warm_start, seeds, ledger, timestamp,
+                              validate, pipeline)
+
+    def _tune(self, benchmark, progress, backend, cache, warm_start, seeds,
+              ledger, timestamp, validate, pipeline) -> TuningResult:
         from repro.obs.metrics import metrics as obs_metrics
 
         from .cache import settings_key
 
         reg = obs_metrics()
-        if validate not in ("off", "warn", "strict"):
-            raise ValueError(f"validate must be 'off', 'warn' or 'strict', "
-                             f"got {validate!r}")
+        # per-session observability deltas: snapshot the process-global
+        # registries before the audit, whose compiles count too, and
+        # report only the movement at exit
+        metrics_at_entry = reg.snapshot()
+        exec_at_entry = default_cache().stats
         if validate != "off":
-            self._validate_workload(benchmark, strict=validate == "strict")
+            with phase("audit"):
+                self._validate_workload(benchmark,
+                                        strict=validate == "strict")
         if backend is None:
             backend = SerialBackend(clock=self.clock)
         strategy = self.strategy
@@ -297,10 +318,6 @@ class Tuner:
                                        worker=outcome.worker))
 
         t0 = self.clock()
-        # per-session observability deltas: snapshot the process-global
-        # registries at entry, report only the movement at exit
-        metrics_at_entry = reg.snapshot()
-        exec_at_entry = default_cache().stats
         recorder = trace_sink()
         try:
             with trace_span(
@@ -361,8 +378,9 @@ class Tuner:
         )
         if ledger is not None:
             # duck-typed BoundLedger so core never imports repro.history
-            ledger.record(result, settings_key=session_key,
-                          timestamp=timestamp, direction=direction)
+            with phase("ledger_io"):
+                ledger.record(result, settings_key=session_key,
+                              timestamp=timestamp, direction=direction)
         return result
 
     def _validate_workload(self, benchmark, strict: bool) -> None:
@@ -390,14 +408,14 @@ class Tuner:
                 raise
             warnings.warn(f"workload audit could not run: "
                           f"{type(e).__name__}: {e}",
-                          WorkloadAuditWarning, stacklevel=3)
+                          WorkloadAuditWarning, stacklevel=4)
             return
         if not findings:
             return
         if strict:
             raise WorkloadAuditError(findings)
         for f in findings:
-            warnings.warn(f.render(), WorkloadAuditWarning, stacklevel=3)
+            warnings.warn(f.render(), WorkloadAuditWarning, stacklevel=4)
 
     def _project_seeds(self, seeds: Sequence[Config]) -> tuple[Config, ...]:
         """Map transfer seeds into this space (nearest in-space config),

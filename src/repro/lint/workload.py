@@ -78,8 +78,10 @@ def trace_cost(fn: Callable, args: Sequence[Any]) -> TracedCost:
     import jax
 
     from repro.analysis.hlo import parse_hlo_cost
+    from repro.core.profiling import compiling
 
-    compiled = jax.jit(fn).lower(*args).compile()
+    with compiling("trace_cost"):
+        compiled = jax.jit(fn).lower(*args).compile()
     ca = compiled.cost_analysis()
     if isinstance(ca, (list, tuple)):
         ca = ca[0] if ca else {}

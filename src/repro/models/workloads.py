@@ -82,7 +82,10 @@ class ModelWorkload:
 
     def compiled(self):
         """Lower + compile once (AOT); callers reuse for text and cost."""
-        return self.jit().lower(*self.args).compile()
+        from repro.core.profiling import compiling
+
+        with compiling("workload"):
+            return self.jit().lower(*self.args).compile()
 
 
 def _tiny_shape(kind: str, batch: int, seq: int) -> WorkloadShape:

@@ -5,7 +5,7 @@ The subsystem is deliberately import-light: no module here imports
 import :mod:`repro.obs.metrics` at the top of the file without creating
 a cycle.  The :class:`TraceRecorder` reaches back into
 ``repro.core.profiling`` only at install time (``__enter__``) to wire
-itself in as the trace sink behind the dual-sink ``phase()`` helpers.
+itself in as the file exporter behind the ``phase()`` span seam.
 """
 
 from .attribution import (AttributedOp, AttributionReport, Roofs, attribute,

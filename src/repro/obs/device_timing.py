@@ -23,8 +23,9 @@ How the trace is read:
 Caveats, all by design:
 
 - a profiled invocation is *slower* than an unprofiled one (the trace
-  collector adds overhead), so the evaluator only profiles one extra
-  sample per incumbent-candidate trial, never the measured samples;
+  collector adds overhead), so these functions profile a call of their
+  own (``chip_smoke.py``, attribution), never a tuning session's
+  measured samples;
 - on the CPU backend XLA emits no device tracks, so the parse finds
   nothing and the functions return ``None`` — callers there degrade to
   host timing;

@@ -3,7 +3,7 @@
 One lock-protected :class:`MetricsRegistry` per process (the
 :func:`metrics` accessor), incremented from the hot paths that already
 hold no other locks: trial start/finish in the executor backends, trial
-cache appends, run-ledger appends, trace-event emission.  Sessions never
+cache appends, run-ledger appends, compiles.  Sessions never
 reset the registry — concurrent sessions share the process — instead
 they take a :meth:`MetricsRegistry.snapshot` at ``tune()`` entry and
 report the :meth:`MetricsRegistry.delta` against it, so back-to-back
@@ -14,7 +14,8 @@ Counter names are dotted, lowercase, and stable once shipped:
 ``trials.started`` / ``trials.completed`` / ``trials.pruned`` /
 ``trials.cached``, ``exec_cache.hits`` / ``.misses`` / ``.compiles``,
 ``cache.appends`` / ``cache.bytes_written``, ``ledger.appends``,
-``trace.events``.
+``compile.calls`` (every lowering plus compile, through
+``repro.core.profiling.compiling``).
 """
 
 from __future__ import annotations

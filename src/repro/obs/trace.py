@@ -26,10 +26,12 @@ install with the trace version, another typically at session end with
 the metrics snapshot).
 
 Installing the recorder (``with TraceRecorder(...)``) wires it into
-``repro.core.profiling`` as the trace sink, which turns every existing
-``phase()`` call site in the evaluator/samplers/exec-cache into a
-dual-sink (bucket + span) with the same no-op fast path when nothing is
-installed.
+``repro.core.profiling`` as the file exporter behind the span seam, so
+every ``phase()`` and ``trace_span()`` site in the engine, evaluator,
+samplers and compile paths becomes a span, with the same no-op fast path
+when nothing is installed. The same sites reach the JAX profiler's trace
+while it collects (``profiling.profiler_spans``); this recorder keeps its
+own clock.
 """
 
 from __future__ import annotations
@@ -39,8 +41,6 @@ import threading
 import time
 from pathlib import Path
 from typing import Any, Callable, Optional
-
-from .metrics import metrics
 
 TRACE_VERSION = 1
 
@@ -160,29 +160,6 @@ class TraceRecorder:
             rec["attrs"] = attrs
         self._emit(rec)
 
-    def add_phase(self, name: str, seconds: float,
-                  at: Optional[float] = None) -> None:
-        """Record an already-measured phase interval as a completed span.
-
-        ``at`` is the interval's *end* on the recorder's clock (defaults
-        to now); samplers that already hold clock readings pass it so
-        back-to-back phases (dispatch then sync) land adjacent rather
-        than overlapping.
-        """
-        end = at if at is not None else self._clock()
-        th = threading.current_thread()
-        stack = self._stack()
-        with self._lock:
-            self._n += 1
-            sid = self._n
-            parent = stack[-1].id if stack else (
-                self._ctx[-1] if self._ctx else None)
-        self._emit({"type": "span", "id": sid, "parent": parent,
-                    "name": name, "cat": "phase",
-                    "ts": round(end - self._t0 - seconds, 9),
-                    "dur": round(max(seconds, 0.0), 9),
-                    "tid": th.ident or 0, "thread": th.name})
-
     def meta_event(self, **fields: Any) -> None:
         """Append a free-form metadata record (metrics snapshots etc.)."""
         self._emit({"type": "meta", **fields})
@@ -227,7 +204,6 @@ class TraceRecorder:
             if self._file is not None:
                 self._file.write(line + "\n")
                 self._file.flush()
-        metrics().inc("trace.events")
 
     def close(self) -> None:
         with self._lock:

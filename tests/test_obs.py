@@ -1,12 +1,12 @@
 """Observability subsystem: span tracing, metrics, exports, device timing.
 
-Covers the trace recorder's nesting/threading semantics, the JSONL and
+Covers the trace recorder's nesting/threading semantics, the program's
+spans on the JAX profiler's trace, the compile counter, the JSONL and
 Chrome-trace (Perfetto) exports, the per-session metrics/exec-cache delta
 discipline, the GitHub Actions annotations emitted by the perf gate, and
 the dashboard drill-down rendering (golden-pinned).
 """
 
-import dataclasses
 import io
 import json
 import os
@@ -20,7 +20,8 @@ import pytest
 from repro.core import (EvaluationSettings, ThreadPoolBackend, Tuner,
                         TuningSession, grid, welford)
 from repro.core.exec_cache import ExecutableCache, default_cache
-from repro.core.profiling import PhaseProfiler, phase, record_phase
+from repro.core import profiling
+from repro.core.profiling import PhaseProfiler, phase
 from repro.history import RunLedger, detect_regressions, render_html
 from repro.history.ledger import RunRecord
 from repro.obs import (MetricsRegistry, TraceRecorder, load_events, metrics,
@@ -90,11 +91,17 @@ def test_recorder_is_exclusive_per_process(tmp_path):
 
 
 def test_phase_feeds_both_profiler_and_trace():
-    prof = PhaseProfiler()
-    with TraceRecorder() as rec, prof:
+    now = [0.0]
+
+    def clock():
+        return now[0]
+
+    prof = PhaseProfiler(clock=clock)
+    with TraceRecorder(clock=clock) as rec, prof:
         with phase("work"):
             pass
-        record_phase("sync", 0.25)
+        with phase("sync"):
+            now[0] += 0.25
     buckets = prof.to_json()
     assert buckets["work"]["count"] == 1
     assert buckets["sync"]["seconds"] == pytest.approx(0.25)
@@ -264,6 +271,160 @@ def test_campaign_trace_spans(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# The program's spans on the JAX profiler's trace
+# ---------------------------------------------------------------------------
+
+
+def timed_benchmark(cfg):
+    from repro.core import timed_sampler
+
+    def factory():
+        return timed_sampler(lambda: sum(range(2000)), work=float(cfg["x"]))
+    return factory
+
+
+def _perfetto_events(log_dir):
+    import gzip
+
+    path = sorted(pathlib.Path(log_dir).rglob("perfetto_trace.json.gz"))[-1]
+    with gzip.open(path, "rt", encoding="utf-8") as fh:
+        return json.load(fh)["traceEvents"]
+
+
+def _inside(inner, outer):
+    return (inner["tid"] == outer["tid"] and outer["ts"] <= inner["ts"]
+            and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"])
+
+
+def test_tune_under_the_profiler_writes_program_spans(tmp_path):
+    """Under ``jax.profiler.trace`` every span of a session lands on the
+    host line of the profiler's own trace as ``repro.<name>``, nested
+    under the caller's annotation, and the spans of one trial carry its
+    index and config label."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path / "prof"),
+                            create_perfetto_trace=True,
+                            profiler_options=options):
+        with jax.profiler.TraceAnnotation("session"):
+            result = TuningSession(
+                "prof", Tuner(grid(x=(3, 5)), SETTINGS), timed_benchmark,
+                cache_dir=tmp_path, fingerprint="fp",
+                benchmark_name="bench").run()
+    assert profiling.trace_sink() is None and profiling._TRACE is None
+
+    events = _perfetto_events(tmp_path / "prof")
+    host = {e["pid"] for e in events
+            if e.get("ph") == "M" and e.get("name") == "process_name"
+            and e["args"]["name"].startswith("/host")}
+    spans = [e for e in events if e.get("ph") == "X" and e["pid"] in host]
+    program = [e for e in spans if e["name"].startswith("repro.")]
+    assert {"repro.tune", "repro.audit", "repro.trial", "repro.build",
+            "repro.invocation", "repro.setup", "repro.dispatch",
+            "repro.stats", "repro.cache_io", "repro.ledger_io"} \
+        <= {e["name"] for e in program}
+    (session,) = [e for e in spans if e["name"] == "session"]
+    assert all(_inside(e, session) for e in program)
+
+    trials = sorted((e for e in program if e["name"] == "repro.trial"),
+                    key=lambda e: e["ts"])
+    assert len(trials) == len(result.trials) == 2
+    assert [t["args"]["trial"] for t in trials] == ["0", "1"]
+    assert [t["args"]["config"] for t in trials] == ["x=3", "x=5"]
+    for t in trials:
+        inner = [e for e in program if e is not t and _inside(e, t)]
+        assert {"repro.build", "repro.invocation", "repro.dispatch"} \
+            <= {e["name"] for e in inner}
+        assert all(e["args"]["trial"] == t["args"]["trial"]
+                   and e["args"]["config"] == t["args"]["config"]
+                   for e in inner)
+
+
+def test_untraced_tune_installs_nothing():
+    """With no profiler collecting and no recorder, every seam returns
+    the shared no-op while a session runs."""
+    seen = []
+
+    def bench(cfg):
+        def factory():
+            seen.append((profiling._TRACE, phase("dispatch"),
+                         profiling.trace_span("trial"),
+                         profiling.compiling("exec_cache")))
+            return lambda: 1.0
+        return factory
+
+    Tuner(grid(x=(1, 2)), SETTINGS).tune(bench, validate="off")
+    assert seen
+    for sink, *handles in seen:
+        assert sink is None
+        assert all(h is profiling._NULL for h in handles)
+
+
+def _compile_case(source):
+    """A callable making one compile through ``source``'s path; returns
+    the ``TuningResult`` where a session made it."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.exec_cache import CompilePipeline
+    from repro.lint import WorkloadSpec
+    from repro.lint.workload import trace_cost
+    from repro.models.workloads import ModelWorkload
+
+    shape = (jax.ShapeDtypeStruct((8,), jnp.float32),)
+    if source == "trace_cost":
+        return lambda: trace_cost(jnp.sin, shape)
+    if source == "workload":
+        return lambda: ModelWorkload(
+            name="w", kind="kernel", fn=jnp.sin, args=(jnp.ones(8),),
+            cfg=None, step=None, shape=None).compiled()
+    if source == "exec_cache":
+        return lambda: ExecutableCache(fingerprint="fp").compile(jnp.cos,
+                                                                 shape)
+    if source == "pipeline":
+        def run():
+            with CompilePipeline() as pipeline:
+                pipeline.submit(lambda: ExecutableCache(
+                    fingerprint="fp").compile(jnp.tan, shape))
+        return run
+
+    def audited(cfg):
+        return lambda: (lambda: 1.0)
+
+    audited.audit_spec = lambda cfg: WorkloadSpec(
+        fn=jnp.sin, args=shape, work=8.0, unit="flops")
+    return lambda: Tuner(grid(x=(1,)), SETTINGS).tune(audited,
+                                                      validate="warn")
+
+
+@pytest.mark.parametrize("source,span_source", [
+    ("trace_cost", "trace_cost"), ("workload", "workload"),
+    ("exec_cache", "exec_cache"), ("pipeline", "pipeline"),
+    ("audit", "trace_cost")])
+def test_compile_calls_count_every_compile(source, span_source):
+    """Every lowering plus compile passes one helper: it counts
+    ``compile.calls`` and opens a ``compile`` span naming its source. The
+    audit's compile lands in the session's own metrics."""
+    import warnings
+
+    reg = metrics()
+    base = reg.snapshot()
+    with TraceRecorder() as rec, warnings.catch_warnings():
+        warnings.simplefilter("ignore")
+        result = _compile_case(source)()
+    assert reg.delta(base)["counters"]["compile.calls"] == 1
+    compiles = [e for e in rec.events()
+                if e["type"] == "span" and e["name"] == "compile"]
+    assert [c["attrs"]["source"] for c in compiles] == [span_source]
+    if source == "audit":
+        assert result.metrics["counters"]["compile.calls"] == 1
+        spans = {e["id"]: e for e in rec.events() if e["type"] == "span"}
+        assert spans[compiles[0]["parent"]]["name"] == "audit"
+
+
+# ---------------------------------------------------------------------------
 # Device timing: graceful degradation off-GPU
 # ---------------------------------------------------------------------------
 
@@ -321,26 +482,6 @@ def test_profile_raises_on_accelerator_without_device_track(monkeypatch,
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with pytest.raises(RuntimeError, match="no device track"):
         device_timing.profile_ops(lambda: sum(range(100)), log_dir=tmp_path)
-
-
-def test_evaluator_emits_device_timing_instant(tmp_path):
-    settings = dataclasses.replace(SETTINGS, device_timing=True)
-    with TraceRecorder(tmp_path / "d.jsonl") as rec:
-        Tuner(grid(x=(5,)), settings).tune(quadratic_benchmark,
-                                           validate="off")
-    names = {e["name"] for e in rec.events() if e["type"] == "instant"}
-    # either a real on-device reading or the explicit unavailable marker —
-    # silence would mean the opt-in was dropped on the floor
-    assert names & {"device_timing", "device_timing_unavailable"}
-
-
-def test_device_timing_skipped_without_recorder():
-    # the profiled invocation is a trace attribute: with no recorder the
-    # evaluator must not pay for it (and must not crash)
-    settings = dataclasses.replace(SETTINGS, device_timing=True)
-    result = Tuner(grid(x=(5,)), settings).tune(quadratic_benchmark,
-                                                validate="off")
-    assert result.best_score == pytest.approx(100.0)
 
 
 # ---------------------------------------------------------------------------
