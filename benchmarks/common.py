@@ -7,6 +7,7 @@ plus richer per-table output to stderr-safe stdout sections.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import math
 from typing import Callable
@@ -20,6 +21,7 @@ from repro.core import (Direction, EvaluationSettings, SearchSpace,
 from repro.core.profiling import phase, trace_instant
 from repro.core.searchspace import doubling_from, powers_of_two
 from repro.lint import WorkloadSpec
+from repro.obs.metrics import metrics
 
 CSV_ROWS: list[tuple[str, float, str]] = []
 
@@ -70,20 +72,46 @@ def triad_kernel(x, y):
     return x + 3.0 * y
 
 
-def _dgemm_data(n: int, m: int, k: int, seed: int, dtype):
-    """Seeded operand generation on the host, then a device transfer.
+@functools.partial(jax.jit, static_argnames=("n", "m", "k", "dtype"))
+def dgemm_operands(seed, *, n: int, m: int, k: int, dtype: str):
+    """Both DGEMM operands from one uint32 ``seed`` below 2**31: ``a`` of
+    shape (n, k) and ``b`` of shape (k, m), standard normal in ``dtype``.
+    ``b`` takes the key of ``seed`` with its top bit set, so it never
+    repeats the stream of any invocation's ``a``. Shapes and dtype are
+    static, so each config is one executable.
 
-    Deliberately *not* ``jax.random``: eager threefry compiles a fresh
-    XLA kernel per operand shape (~150ms measured on host CPU), so a
-    tuning campaign — where every trial visits a cold shape — would pay
-    a data-generation compile it never amortizes. A seeded numpy
-    Generator is deterministic, shape-oblivious and compile-free, and
-    GEMM is data-oblivious, so operand provenance cannot shift the
-    measurement."""
-    rng = np.random.default_rng(seed)
-    a = np.asarray(rng.standard_normal((n, k)), dtype=jnp.dtype(dtype))
-    b = np.asarray(rng.standard_normal((k, m)), dtype=jnp.dtype(dtype))
-    return jax.device_put(a), jax.device_put(b)
+    The keys are ``rbg`` keys, whose bits come from XLA's RngBitGenerator,
+    one HLO op. A threefry key lowers its twenty rounds by tracing them in
+    Python again for every shape: most of a second per config of set-up
+    on a TPU v5e host, even with the executable in the persistent
+    compilation cache."""
+    top = jnp.uint32(1 << 31)
+    return (jax.random.normal(jax.random.key(seed, impl="rbg"), (n, k), dtype),
+            jax.random.normal(jax.random.key(seed | top, impl="rbg"),
+                              (k, m), dtype))
+
+
+def _dgemm_draw(cache, n: int, m: int, k: int, dtype):
+    """The operand generator's executable for one config, from ``cache``."""
+    return cache.compile(dgemm_operands,
+                         (jax.ShapeDtypeStruct((), jnp.uint32),),
+                         static={"n": n, "m": m, "k": k,
+                                 "dtype": jnp.dtype(dtype).name})
+
+
+def _dgemm_data(n: int, m: int, k: int, seed: int, dtype, cache=None):
+    """Seeded operands drawn on the device by a precompiled generator.
+
+    The generator (:func:`dgemm_operands`) is one executable per config
+    in the :class:`~repro.core.exec_cache.ExecutableCache`, compiled by
+    :func:`dgemm_precompile` during set-up (from JAX's persistent cache
+    after a checkout's first run), so a draw compiles nothing, moves no
+    operand over the host link and takes milliseconds of device time.
+    The same seed gives the same operands; GEMM is data-oblivious, so
+    operand provenance cannot shift the measurement."""
+    draw = _dgemm_draw(cache if cache is not None else default_cache(),
+                       n, m, k, dtype)
+    return draw(np.uint32(seed))
 
 
 def dgemm_invocation_factory(n: int, m: int, k: int,
@@ -106,12 +134,15 @@ def dgemm_invocation_factory(n: int, m: int, k: int,
     invocations so calibration runs once per config. ``reuse_data=True``
     allocates operand data once per *config* instead of once per
     invocation — sound for GEMM on normal data because its runtime is
-    data-oblivious, and it removes the dominant setup cost of short
-    trials.
+    data-oblivious.
 
-    The data seed is derived from the matrix dimensions plus an invocation
+    Operands are drawn on the device by the precompiled generator of
+    :func:`_dgemm_data`, served by the same cache as the kernel, so a
+    fresh draw costs milliseconds of device time and no compile. The
+    data seed is derived from the matrix dimensions plus an invocation
     counter — deterministic across reruns (reproducible cache keys and
-    resumable sessions) while still varying between invocations."""
+    resumable sessions) while still varying between invocations. Each
+    draw counts in the ``operands.device_draws`` metric."""
     flops = dgemm_flops(n, m, k)
     invocation = itertools.count()
     cache = exec_cache if exec_cache is not None else default_cache()
@@ -125,7 +156,8 @@ def dgemm_invocation_factory(n: int, m: int, k: int,
                 a, b = state["data"]
             else:
                 a, b = jax.block_until_ready(
-                    _dgemm_data(n, m, k, seed, dtype))
+                    _dgemm_data(n, m, k, seed, dtype, cache))
+                metrics().inc("operands.device_draws")
                 if reuse_data:
                     state["data"] = (a, b)
         f = cache.compile(jnp.dot, (a, b))
@@ -225,10 +257,12 @@ def triad_benchmark(cfg: dict) -> Callable:
 #    CompilePipeline so trial k+1 compiles while trial k measures) ----------
 
 def dgemm_precompile(cfg: dict) -> None:
-    """Warm the executable cache for one DGEMM config — ShapeDtypeStructs
-    only, nothing is allocated or executed."""
+    """Warm the executable cache for one DGEMM config, the operand
+    generator and the kernel — ShapeDtypeStructs only, nothing is
+    allocated or executed."""
     n, m, k = cfg["n"], cfg["m"], cfg["k"]
     cache = default_cache()
+    _dgemm_draw(cache, n, m, k, jnp.float32)
     cache.compile(jnp.dot,
                   (jax.ShapeDtypeStruct((n, k), jnp.float32),
                    jax.ShapeDtypeStruct((k, m), jnp.float32)))

@@ -18,7 +18,8 @@ generation:
   fast    the shipping path: AOT ``ExecutableCache`` for kernels,
           pipelined compiles overlapping the previous trial's
           measurement, batched ``steady_sampler`` observations,
-          host-side seeded data generation reused per config
+          seeded operands drawn on the device by a precompiled
+          generator, reused per config
 
 and reports the **non-measured wall time per trial**::
 

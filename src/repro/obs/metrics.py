@@ -15,7 +15,8 @@ Counter names are dotted, lowercase, and stable once shipped:
 ``trials.cached``, ``exec_cache.hits`` / ``.misses`` / ``.compiles``,
 ``cache.appends`` / ``cache.bytes_written``, ``ledger.appends``,
 ``compile.calls`` (every lowering plus compile, through
-``repro.core.profiling.compiling``).
+``repro.core.profiling.compiling``), ``operands.device_draws`` (DGEMM
+invocations whose operands were drawn on the device).
 """
 
 from __future__ import annotations

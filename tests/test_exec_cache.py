@@ -201,10 +201,10 @@ def test_tuner_pipeline_off_and_missing_hook():
 
 
 def test_factory_compiles_once_across_invocations():
-    """The PR 8 satellite regression test: N invocations of one config
-    must compile exactly once (the pre-PR factories re-entered jax.jit
-    per invocation)."""
-    from benchmarks.common import dgemm_invocation_factory
+    """N invocations of one config compile the kernel and the operand
+    generator exactly once each (factories that re-entered jax.jit per
+    invocation compiled N times)."""
+    from benchmarks.common import dgemm_invocation_factory, dgemm_operands
 
     cache = ExecutableCache(fingerprint="test")
     factory = dgemm_invocation_factory(16, 16, 8, exec_cache=cache)
@@ -212,8 +212,14 @@ def test_factory_compiles_once_across_invocations():
         sample = factory()
         assert sample() > 0.0                # GFLOP/s
     s = cache.stats
-    assert s.compiles == 1
-    assert s.hits == 3
+    assert s.compiles == 2
+    assert s.hits == 6
+    assert cache.key_for(jnp.dot,
+                         (jax.ShapeDtypeStruct((16, 8), jnp.float32),
+                          jax.ShapeDtypeStruct((8, 16), jnp.float32))) in cache
+    assert cache.key_for(dgemm_operands,
+                         (jax.ShapeDtypeStruct((), jnp.uint32),),
+                         {"n": 16, "m": 16, "k": 8, "dtype": "float32"}) in cache
 
 
 # ---------------------------------------------------------------------------
