@@ -462,7 +462,9 @@ def model_step_workload(workload: str, arch, cfg: dict, *,
         use_flash=bool(cfg.get("use_flash", 0)),
         flash_block_q=int(cfg.get("flash_block_q", 512)),
         flash_block_k=int(cfg.get("flash_block_k", 512)),
-        remat=bool(cfg.get("remat", 0)))
+        remat=bool(cfg.get("remat", 0)),
+        use_pallas_ssd=bool(cfg.get("use_pallas_ssd", 0)),
+        ssd_chunk=int(cfg.get("ssd_chunk", 256)))
     return build_workload(workload, arch, step=step,
                           batch_size=batch_size, seq_len=seq_len)
 

@@ -21,6 +21,7 @@ ARCH_IDS = [
     "llama_3_2_vision_11b",
     "mamba2_130m",
     "zamba2_2_7b",
+    "granite_4_0_h_micro",
 ]
 
 _ALIAS = {i.replace("_", "-"): i for i in ARCH_IDS}

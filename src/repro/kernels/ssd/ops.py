@@ -27,6 +27,12 @@ def ssd_chunk_scan(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
       cm: (B, S, N)     C projections
       chunk: chunk length Q (S % Q == 0); the tunable.
     Returns (B, S, H, P) in f32.
+
+    Differentiable: ``pallas_call`` defines no autodiff rule, so the
+    kernel carries a custom VJP whose backward differentiates
+    ``ssd_chunk_scan_ref`` on the same kernel-layout inputs, the pattern
+    of ``kernels/flash_attention/ops.py``. The layout preparation around
+    it (x·dt, the within-chunk cumsum) is differentiated by JAX.
     """
     B, S, H, P = x.shape
     N = bm.shape[-1]
@@ -38,9 +44,22 @@ def ssd_chunk_scan(x: jax.Array, dt: jax.Array, a: jax.Array, bm: jax.Array,
     cum = jnp.moveaxis(cum, 3, 1)                        # (B,H,C,Q)
     bm_c = bm.reshape(B, C, Q, N)
     cm_c = cm.reshape(B, C, Q, N)
-    fn = ssd_chunk_scan_pallas if use_pallas else \
-        (lambda *args, **kw: ssd_chunk_scan_ref(*args))
-    y = fn(xdt, bm_c, cm_c, cum, **({"interpret": interpret}
-                                    if use_pallas else {}))
+    if use_pallas:
+        @jax.custom_vjp
+        def scan(xdt, bm_c, cm_c, cum):
+            return ssd_chunk_scan_pallas(xdt, bm_c, cm_c, cum,
+                                         interpret=interpret)
+
+        def scan_fwd(*args):
+            return scan(*args), args
+
+        def scan_bwd(args, g):
+            _, vjp = jax.vjp(ssd_chunk_scan_ref, *args)
+            return vjp(g)
+
+        scan.defvjp(scan_fwd, scan_bwd)
+    else:
+        scan = ssd_chunk_scan_ref
+    y = scan(xdt, bm_c, cm_c, cum)
     y = jnp.moveaxis(y, 1, 3).reshape(B, S, H, P)        # back to (B,S,H,P)
     return y

@@ -22,7 +22,9 @@ def ssd_chunk_scan_ref(xdt: jax.Array, bm: jax.Array, cm: jax.Array,
             x_c, b_c, c_c, u_c = inputs
             diff = u_c[:, None] - u_c[None, :]
             mask = jnp.tril(jnp.ones((Q, Q), bool))
-            decay = jnp.where(mask, jnp.exp(diff), 0.0)
+            # mask before exp: above the diagonal diff > 0 can overflow,
+            # and where's gradient times an inf there is NaN
+            decay = jnp.exp(jnp.where(mask, diff, -jnp.inf))
             scores = c_c @ b_c.T
             y = (scores * decay) @ x_c
             y = y + (c_c @ h.T) * jnp.exp(u_c)[:, None]

@@ -1,6 +1,7 @@
 """Pallas TPU kernel for the Mamba2 SSD chunk scan (Dao & Gu 2024).
 
-The hot loop of the SSM family (mamba2-130m, zamba2-2.7b): per (batch,
+The hot loop of the SSM families (mamba2-130m, zamba2-2.7b, and the
+Mamba2 layers of granite-4.0-h, whose train step calls it): per (batch,
 head), chunks of the sequence are processed with an attention-like
 quadratic intra-chunk term while a (P, N) state carries across chunks.
 The chunk axis is sequential ("arbitrary") and the running state lives in
@@ -106,6 +107,7 @@ def ssd_chunk_scan_pallas(xdt: jax.Array, bm: jax.Array, cm: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="ssd_chunk_scan",
     )(xdt.astype(jnp.float32), bm.astype(jnp.float32),
       cm.astype(jnp.float32), cum[..., None], cum[..., None, :])
 

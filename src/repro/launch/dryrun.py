@@ -48,6 +48,10 @@ def layer_unit(cfg: ModelConfig) -> int:
         return cfg.cross_attn_every
     if cfg.family == "hybrid" and cfg.attn_every:
         return cfg.attn_every
+    if cfg.family == "granite_hybrid":
+        from ..models.granite_hybrid import pattern
+        periods, pre, post = pattern(cfg)
+        return pre + 1 + post
     return 1
 
 

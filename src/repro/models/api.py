@@ -17,7 +17,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from . import encdec, hybrid, layers, transformer
+from . import encdec, granite_hybrid, hybrid, layers, transformer
 from .config import ModelConfig, WorkloadShape, cache_len
 from .transformer import StepConfig
 
@@ -33,6 +33,8 @@ def param_defs(cfg: ModelConfig) -> dict:
         return hybrid.ssm_lm_defs(cfg)
     if cfg.family == "hybrid":
         return hybrid.hybrid_lm_defs(cfg)
+    if cfg.family == "granite_hybrid":
+        return granite_hybrid.param_defs(cfg)
     raise ValueError(f"unknown family {cfg.family!r}")
 
 
@@ -40,12 +42,10 @@ def loss_fn(params: dict, batch: dict, cfg: ModelConfig,
             step: StepConfig) -> jax.Array:
     if cfg.family == "encdec":
         return encdec.loss_fn(params, batch, cfg, step)
+    if cfg.family == "granite_hybrid":
+        return granite_hybrid.loss_fn(params, batch, cfg, step)
     if cfg.family in ("ssm", "hybrid"):
-        tokens = batch["tokens"]
-        h = hybrid.hidden(params, tokens, cfg, step)
-        targets, mask = layers.next_token_targets(tokens)
-        return layers.cross_entropy_loss(params["embed"], h, targets, cfg,
-                                         chunk=step.loss_chunk, mask=mask)
+        return hybrid.loss_fn(params, batch, cfg, step)
     return transformer.lm_loss(params, batch, cfg, step)
 
 
@@ -55,6 +55,8 @@ def prefill_fn(params: dict, batch: dict, cfg: ModelConfig,
     step = dataclasses.replace(step, inference=True)
     if cfg.family == "encdec":
         return encdec.prefill(params, batch, cfg, step)
+    if cfg.family == "granite_hybrid":
+        return granite_hybrid.prefill(params, batch, cfg, step)
     if cfg.family in ("ssm", "hybrid"):
         return hybrid.prefill(params, batch, cfg, step)
     if cfg.family == "vlm":
@@ -69,6 +71,8 @@ def decode_fn(params: dict, batch: dict, cache: dict, pos: jax.Array,
     tokens = batch["tokens"]
     if cfg.family == "encdec":
         return encdec.decode(params, tokens, cache, pos, cfg, step)
+    if cfg.family == "granite_hybrid":
+        return granite_hybrid.decode(params, tokens, cache, pos, cfg, step)
     if cfg.family in ("ssm", "hybrid"):
         return hybrid.decode(params, tokens, cache, pos, cfg, step)
     return transformer.lm_decode(params, tokens, cache, pos, cfg, step,
@@ -81,6 +85,8 @@ def cache_shapes(cfg: ModelConfig, shape: WorkloadShape) -> dict:
     length = cache_len(cfg, shape)
     if cfg.family == "encdec":
         return encdec.cache_shapes(cfg, B, length)
+    if cfg.family == "granite_hybrid":
+        return granite_hybrid.cache_shapes(cfg, B, length)
     if cfg.family in ("ssm", "hybrid"):
         return hybrid.cache_shapes(cfg, B, length)
     if cfg.family == "vlm":
@@ -95,6 +101,8 @@ def cache_logical(cfg: ModelConfig) -> dict:
     kv_logical = layers.KVCacheSpec(1, 1, 1, 1, 1, jnp.bfloat16).logical
     if cfg.family == "encdec":
         return encdec.cache_logical(cfg)
+    if cfg.family == "granite_hybrid":
+        return granite_hybrid.cache_logical(cfg)
     if cfg.family in ("ssm", "hybrid"):
         return hybrid.cache_logical(cfg)
     return {"attn": kv_logical}

@@ -1,8 +1,8 @@
 """Model and workload configuration.
 
-``ModelConfig`` covers all six architecture families in the assigned pool
-(dense / moe / enc-dec audio / vlm / ssm / hybrid). Workload shapes are the
-four assigned input-shape cells; ``input_specs`` produces the
+``ModelConfig`` covers the architecture families of the registry (dense /
+moe / enc-dec audio / vlm / ssm / hybrid / granite_hybrid). Workload
+shapes are the four assigned input-shape cells; ``input_specs`` produces the
 ShapeDtypeStruct stand-ins the dry-run lowers against.
 """
 
@@ -22,7 +22,7 @@ def _round_up(x: int, mult: int) -> int:
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                     # dense | moe | encdec | vlm | ssm | hybrid
+    family: str    # dense | moe | encdec | vlm | ssm | hybrid | granite_hybrid
     n_layers: int
     d_model: int
     n_heads: int
@@ -58,6 +58,13 @@ class ModelConfig:
     ssm_chunk: int = 256
     conv_width: int = 4
     attn_every: int = 0             # zamba2: shared attn block interval
+    conv_bias: bool = False         # bias on the Mamba2 depthwise conv
+    # --- granite_hybrid (granite-4.0-h): per-layer mixer kinds ---
+    layer_types: tuple[str, ...] = ()   # "mamba" | "attention", per layer
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float | None = None   # required by granite_hybrid
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     # --- training ---
     lr_schedule: str = "cosine"     # cosine | wsd (MiniCPM)
 

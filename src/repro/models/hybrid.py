@@ -120,6 +120,15 @@ def hidden(params: dict, tokens: jax.Array, cfg: ModelConfig,
     return L.apply_norm(params["ln_f"], h, cfg)
 
 
+def loss_fn(params: dict, batch: dict, cfg: ModelConfig,
+            step: StepConfig) -> jax.Array:
+    tokens = batch["tokens"]
+    h = hidden(params, tokens, cfg, step)
+    targets, mask = L.next_token_targets(tokens)
+    return L.cross_entropy_loss(params["embed"], h, targets, cfg,
+                                chunk=step.loss_chunk, mask=mask)
+
+
 def prefill(params: dict, batch: dict, cfg: ModelConfig,
             step: StepConfig) -> tuple[jax.Array, dict]:
     tokens = batch["tokens"]

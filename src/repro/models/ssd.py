@@ -56,16 +56,24 @@ def ssd_defs(cfg: ModelConfig, layers: int | None = None) -> dict:
         "d_skip": small((H,), init="ones"),
         "norm": w((Din,), (None,), init="ones"),
         "out_proj": w((Din, D), ("ssm_inner", "embed")),
+        **({"conv_bias_x": w((Din,), ("ssm_inner",), scale=0.1),
+            "conv_bias_b": w((N,), ("ssm_state",), scale=0.1),
+            "conv_bias_c": w((N,), ("ssm_state",), scale=0.1)}
+           if cfg.conv_bias else {}),
     }
 
 
-def _causal_conv(x: jax.Array, w: jax.Array) -> jax.Array:
-    """Depthwise causal conv, width W (small): x (B, S, C), w (W, C)."""
+def _causal_conv(x: jax.Array, w: jax.Array,
+                 bias: jax.Array | None = None) -> jax.Array:
+    """Depthwise causal conv, width W (small): x (B, S, C), w (W, C),
+    optional bias (C,)."""
     W = w.shape[0]
     pad = jnp.pad(x, ((0, 0), (W - 1, 0), (0, 0)))
     out = jnp.zeros_like(x, dtype=jnp.float32)
     for i in range(W):
         out = out + pad[:, i:i + x.shape[1]].astype(jnp.float32) * w[i].astype(jnp.float32)
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
     return out.astype(x.dtype)
 
 
@@ -84,25 +92,36 @@ def _project(p: dict, u: jax.Array, cfg: ModelConfig):
 
 
 def ssd_forward(p: dict, u: jax.Array, cfg: ModelConfig,
-                h0: jax.Array | None = None, return_state: bool = False):
+                h0: jax.Array | None = None, return_state: bool = False, *,
+                chunk: int | None = None, use_pallas: bool = False):
     """Full-sequence SSD. u: (B, S, D) -> (B, S, D).
 
     ``return_state=True`` additionally returns the decode cache
     {ssm (B,H,P,N) f32, conv (B,W-1,Din+2N)} after the last position
-    (prefill path)."""
+    (prefill path). ``chunk`` overrides ``cfg.ssm_chunk``;
+    ``use_pallas`` runs the chunk scan in the Pallas kernel
+    (``kernels.ssd.ssd_chunk_scan``, train only: no ``h0``, no state)."""
     import math
     B, S, D = u.shape
     H, P, N, Q = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state,
-                  min(cfg.ssm_chunk, S))
+                  min(chunk or cfg.ssm_chunk, S))
     if S % Q:
         Q = math.gcd(S, Q)  # odd test lengths: largest common chunk
     z, x, b, c, dt, A = _project(p, u, cfg)
     if return_state:
         W = cfg.conv_width
         conv_tail = jnp.concatenate([x, b, c], axis=-1)[:, S - (W - 1):, :]
-    x = jax.nn.silu(_causal_conv(x, p["conv_x"]))
-    b = jax.nn.silu(_causal_conv(b, p["conv_b"]))
-    c = jax.nn.silu(_causal_conv(c, p["conv_c"]))
+    x = jax.nn.silu(_causal_conv(x, p["conv_x"], p.get("conv_bias_x")))
+    b = jax.nn.silu(_causal_conv(b, p["conv_b"], p.get("conv_bias_b")))
+    c = jax.nn.silu(_causal_conv(c, p["conv_c"], p.get("conv_bias_c")))
+    if use_pallas:
+        if return_state or h0 is not None:
+            raise ValueError("the Pallas SSD scan carries no state in or out")
+        from ..kernels.platform import interpret_mode
+        from ..kernels.ssd import ssd_chunk_scan
+        y = ssd_chunk_scan(x.reshape(B, S, H, P), dt, A, b, c, chunk=Q,
+                           interpret=interpret_mode())
+        return _gate_out(p, y, x, z, u, cfg)
 
     nc = S // Q
     xh = x.reshape(B, nc, Q, H, P).astype(jnp.float32)
@@ -121,7 +140,9 @@ def ssd_forward(p: dict, u: jax.Array, cfg: ModelConfig,
         cum_t = jnp.moveaxis(cum, 1, 2)          # (B,H,Q)
         diff = cum_t[:, :, :, None] - cum_t[:, :, None, :]   # (B,H,Q,Q)
         mask = jnp.tril(jnp.ones((Q, Q), bool))
-        L = jnp.where(mask, jnp.exp(diff), 0.0)
+        # mask before exp: above the diagonal diff > 0 can overflow, and
+        # where's gradient times an inf there is NaN
+        L = jnp.exp(jnp.where(mask, diff, -jnp.inf))
         scores = jnp.einsum("bin,bjn->bij", cc, bc)          # (B,Q,Q)
         xdt = xc * dtc[..., None]                            # (B,Q,H,P)
         y_intra = jnp.einsum("bij,bhij,bjhp->bihp", scores, L, xdt)
@@ -140,17 +161,24 @@ def ssd_forward(p: dict, u: jax.Array, cfg: ModelConfig,
               jnp.moveaxis(ch, 1, 0), jnp.moveaxis(dth, 1, 0))
     h_final, ys = xscan(chunk_body, h0, inputs)       # (nc,B,Q,H,P)
     y = jnp.moveaxis(ys, 0, 1).reshape(B, S, H, P)
-    y = y + p["d_skip"][:, None] * x.reshape(B, S, H, P).astype(jnp.float32)
-    y = y.reshape(B, S, cfg.d_inner)
-    # gated RMSNorm (y * silu(z), normalized)
-    g = y * jax.nn.silu(z.astype(jnp.float32))
-    var = jnp.mean(jnp.square(g), axis=-1, keepdims=True)
-    g = g * jax.lax.rsqrt(var + cfg.norm_eps) * p["norm"].astype(jnp.float32)
-    out = jnp.einsum("bsi,id->bsd", g.astype(u.dtype), p["out_proj"])
+    out = _gate_out(p, y, x, z, u, cfg)
     if return_state:
         return out, {"ssm": h_final,
                      "conv": conv_tail.astype(cfg.jdtype)}
     return out
+
+
+def _gate_out(p: dict, y: jax.Array, x: jax.Array, z: jax.Array,
+              u: jax.Array, cfg: ModelConfig) -> jax.Array:
+    """D skip, gated RMSNorm (y * silu(z), normalized) and out_proj of the
+    scan's y (B, S, H, P)."""
+    B, S, H, P = y.shape
+    y = y + p["d_skip"][:, None] * x.reshape(B, S, H, P).astype(jnp.float32)
+    y = y.reshape(B, S, cfg.d_inner)
+    g = y * jax.nn.silu(z.astype(jnp.float32))
+    var = jnp.mean(jnp.square(g), axis=-1, keepdims=True)
+    g = g * jax.lax.rsqrt(var + cfg.norm_eps) * p["norm"].astype(jnp.float32)
+    return jnp.einsum("bsi,id->bsd", g.astype(u.dtype), p["out_proj"])
 
 
 # ---------------------------------------------------------------------------
@@ -191,6 +219,10 @@ def ssd_decode(p: dict, u: jax.Array, cache: dict,
     w_full = jnp.concatenate([p["conv_x"], p["conv_b"], p["conv_c"]], axis=1)
     conv_out = jnp.einsum("bwc,wc->bc", window.astype(jnp.float32),
                           w_full.astype(jnp.float32))
+    if cfg.conv_bias:
+        conv_out = conv_out + jnp.concatenate(
+            [p["conv_bias_x"], p["conv_bias_b"], p["conv_bias_c"]]
+        ).astype(jnp.float32)
     conv_out = jax.nn.silu(conv_out)
     x = conv_out[:, :cfg.d_inner]
     b = conv_out[:, cfg.d_inner:cfg.d_inner + N]

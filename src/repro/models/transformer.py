@@ -34,6 +34,8 @@ class StepConfig:
     microbatches: int = 1
     inference: bool = False       # prefill/decode: drop-free MoE routing
     grad_bf16: bool = False       # cast grads before sync (halves traffic)
+    use_pallas_ssd: bool = False  # Pallas SSD chunk scan (granite_hybrid)
+    ssd_chunk: int = 256          # SSD chunk length (granite_hybrid)
 
     def policy(self):
         if not self.remat:
@@ -59,10 +61,9 @@ def _block_defs(cfg: ModelConfig, layers: int) -> dict:
 
 def lm_defs(cfg: ModelConfig) -> dict:
     """Dense / MoE decoder-only parameter tree."""
-    out = {"embed": L.embedding_defs(cfg),
-           "layers": _block_defs(cfg, cfg.n_layers),
-           "ln_f": L.norm_defs(cfg)}
-    return out
+    return {"embed": L.embedding_defs(cfg),
+            "layers": _block_defs(cfg, cfg.n_layers),
+            "ln_f": L.norm_defs(cfg)}
 
 
 def vlm_defs(cfg: ModelConfig) -> dict:
@@ -87,8 +88,7 @@ def vlm_defs(cfg: ModelConfig) -> dict:
 # ---------------------------------------------------------------------------
 
 
-def _ffn(lp: dict, x: jax.Array, cfg: ModelConfig,
-         step: StepConfig = StepConfig()) -> jax.Array:
+def _ffn(lp: dict, x: jax.Array, cfg: ModelConfig, step: StepConfig = StepConfig()) -> jax.Array:
     if cfg.n_experts:
         return moe_lib.apply_moe(lp["moe"], x, cfg, drop=not step.inference)
     return L.apply_mlp(lp["mlp"], x, cfg)

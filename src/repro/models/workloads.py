@@ -238,6 +238,9 @@ def build_workload(name: str, arch: "str | ModelConfig | None" = None, *,
 
         cfg = get_smoke(arch)
     step = step or StepConfig(remat=False)
+    if cfg.family == "granite_hybrid":
+        from .granite_hybrid import count_build
+        count_build(cfg, step)
     builder = {"train_step": _build_train, "prefill_step": _build_prefill,
                "decode_step": _build_decode}[name]
     return builder(cfg, step, batch_size, seq_len)

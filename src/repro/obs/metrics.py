@@ -16,7 +16,10 @@ Counter names are dotted, lowercase, and stable once shipped:
 ``cache.appends`` / ``cache.bytes_written``, ``ledger.appends``,
 ``compile.calls`` (every lowering plus compile, through
 ``repro.core.profiling.compiling``), ``operands.device_draws`` (DGEMM
-invocations whose operands were drawn on the device).
+invocations whose operands were drawn on the device),
+``model.mamba_layers`` / ``model.attention_layers`` / ``ssd.pallas_calls``
+(per granite-hybrid step program built: its Mamba and attention layers,
+and the Pallas SSD call sites its forward lowers).
 """
 
 from __future__ import annotations

@@ -116,3 +116,22 @@ def test_granite_train_step_fits_one_chip(shapes, monkeypatch):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < HBM_BYTES
+
+
+@pytest.mark.parametrize("chunk", [128, 256])
+def test_ssd_granite4h_train_compiles(shapes, chunk):
+    """The SSD scan of one granite-4.0-h-micro Mamba2 layer at 8192 tokens
+    (64 heads of 64, state 128), differentiated: the Pallas forward and the
+    reference backward of its custom VJP."""
+    from repro.kernels.ssd import ssd_chunk_scan
+
+    b, s, h, p, n = 1, 8192, 64, 64, 128
+
+    def loss(x, dt, a, bm, cm):
+        return jnp.sum(ssd_chunk_scan(x, dt, a, bm, cm, chunk=chunk))
+
+    # the value keeps the forward: jax.grad alone would drop the kernel
+    _compile(jax.value_and_grad(loss, argnums=(0, 1, 3, 4)),
+             shapes((b, s, h, p)),
+             shapes((b, s, h)), shapes((h,)), shapes((b, s, n)),
+             shapes((b, s, n)))
