@@ -24,12 +24,11 @@ before the tied head). Attention is NoPE: no position embedding.
 The dense decoder's pieces are reused only by calling them
 (``layers.apply_norm``, ``apply_mlp``, ``_qkv``, ``_attend``,
 ``cross_entropy_loss``, ``xscan``, the flash kernel); none is adapted to
-this model. The flash forward is the Pallas kernel; its backward here
-differentiates the q-chunked ``layers._attend``, since the kernel's own
-reference backward holds the whole (B, H, S, S) score matrix, 8.6 GB in
-float32 at 32 heads and 8192 positions. Mamba mixers run the SSD scan in
-the Pallas kernel when ``step.use_pallas_ssd`` is set, at chunk
-``step.ssd_chunk`` (``models/ssd.py``).
+this model. With ``step.use_flash`` attention runs the Pallas flash
+kernel forward and backward, as ``layers.attention_full`` does; its
+backward holds no (S, S) scores, so it serves 8192 positions. Mamba
+mixers run the SSD scan in the Pallas kernel when ``step.use_pallas_ssd``
+is set, at chunk ``step.ssd_chunk`` (``models/ssd.py``).
 
 Decode carries each Mamba layer's SSM and conv states and each attention
 layer's KV cache, in the same per-period stacks as the parameters.
@@ -139,29 +138,6 @@ def _q_scale(cfg: ModelConfig) -> float:
     return cfg.attention_multiplier * math.sqrt(cfg.head_dim_)
 
 
-def _flash(q, k, v, cfg: ModelConfig, step: StepConfig):
-    """Causal attention: the Pallas flash forward, and the backward of the
-    q-chunked ``layers._attend``, which never holds more than a
-    (B, H, 1024, S) slab of scores."""
-
-    @jax.custom_vjp
-    def attend(q, k, v):
-        return flash_attention(q, k, v, causal=True, window=cfg.window,
-                               bq=step.flash_block_q, bk=step.flash_block_k,
-                               interpret=interpret_mode())
-
-    def attend_fwd(q, k, v):
-        return attend(q, k, v), (q, k, v)
-
-    def attend_bwd(res, g):
-        _, vjp = jax.vjp(lambda q_, k_, v_: L._attend(
-            q_, k_, v_, causal=True, window=cfg.window), *res)
-        return vjp(g)
-
-    attend.defvjp(attend_fwd, attend_bwd)
-    return attend(q, k, v)
-
-
 def _qkv(p: dict, x: jax.Array, cfg: ModelConfig):
     """q (scaled for the attention multiplier), k, v; no rotary (NoPE)."""
     q, k, v = L._qkv(p, x, x)
@@ -188,7 +164,10 @@ def _attention_block(h: jax.Array, lp: dict, cfg: ModelConfig,
     with jax.named_scope("attention"):
         q, k, v = _qkv(lp["attn"], L.apply_norm(lp["ln1"], h, cfg), cfg)
         if step.use_flash:
-            out = _flash(q, k, v, cfg, step)
+            out = flash_attention(q, k, v, causal=True, window=cfg.window,
+                                  bq=step.flash_block_q,
+                                  bk=step.flash_block_k,
+                                  interpret=interpret_mode())
         else:
             out = L._attend(q, k, v, causal=True, window=cfg.window)
         y = shard_act(jnp.einsum("bhsk,hkd->bsd", out, lp["attn"]["wo"]),

@@ -209,6 +209,23 @@ def _build_dgemm(m: int, n: int, k: int) -> ModelWorkload:
                          declared_flops=2.0 * m * n * k)
 
 
+def _count_flash_backward(cfg: ModelConfig) -> None:
+    """Counter ``flash.bwd_pallas_calls`` of one train step program built
+    with ``use_flash``: the attention layers whose backward lowers the
+    Pallas flash kernels (every layer of the dense and MoE decoders, the
+    attention layers of the granite-4.0-h assembly)."""
+    from ..obs.metrics import metrics
+
+    if cfg.family in ("dense", "moe"):
+        layers = cfg.n_layers
+    elif cfg.family == "granite_hybrid":
+        from .granite_hybrid import n_mixers
+        layers = n_mixers(cfg)[1]
+    else:
+        return
+    metrics().inc("flash.bwd_pallas_calls", layers)
+
+
 def build_workload(name: str, arch: "str | ModelConfig | None" = None, *,
                    step: Optional[StepConfig] = None,
                    batch_size: int = _TINY_BATCH, seq_len: int = _TINY_SEQ,
@@ -241,6 +258,8 @@ def build_workload(name: str, arch: "str | ModelConfig | None" = None, *,
     if cfg.family == "granite_hybrid":
         from .granite_hybrid import count_build
         count_build(cfg, step)
+    if name == "train_step" and step.use_flash:
+        _count_flash_backward(cfg)
     builder = {"train_step": _build_train, "prefill_step": _build_prefill,
                "decode_step": _build_decode}[name]
     return builder(cfg, step, batch_size, seq_len)

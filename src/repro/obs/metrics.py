@@ -19,7 +19,11 @@ Counter names are dotted, lowercase, and stable once shipped:
 invocations whose operands were drawn on the device),
 ``model.mamba_layers`` / ``model.attention_layers`` / ``ssd.pallas_calls``
 (per granite-hybrid step program built: its Mamba and attention layers,
-and the Pallas SSD call sites its forward lowers).
+and the Pallas SSD call sites its forward lowers),
+``flash.bwd_pallas_calls`` (per train step program built with
+``use_flash``: the attention layers whose backward lowers the Pallas
+flash kernels, n_layers in the dense decoder, the attention layers in the
+granite hybrid; absent without ``use_flash``).
 """
 
 from __future__ import annotations

@@ -2,8 +2,9 @@
 gradient leaf against the plain float32 reference ``perfbench/granite4h_ref``
 on seeded random weights, with the SSD scan in ``jax.numpy`` and in the
 Pallas kernel (interpret mode), at two chunks; the layer pattern; the
-reference's own chunked scan against the sequential recurrence; the
-counters a build adds.
+reference's own chunked scan against the sequential recurrence; one
+attention block's gradients through the flash kernel's backward against
+the jnp attention; the counters a build adds.
 
 The size: two periods of 10 layers, d_model 128, 4/2 attention heads of
 32, d_inner 256 (8 SSM heads of 32), state 16, vocabulary 512, one
@@ -137,6 +138,35 @@ def test_bfloat16_program_within_the_cell_limits(setup):
     assert control[1] > 3 * program[1]
 
 
+@pytest.mark.parametrize("bq,bk", [(128, 128), (64, 128)])
+def test_attention_block_gradients_flash_on_and_off(setup, bq, bk):
+    """One attention block's parameter gradients through the flash
+    kernel's Pallas backward (interpret mode) match those through the
+    jnp attention, leaf by leaf."""
+    arch, params, _ = setup
+    lp = jax.tree.map(lambda a: a[0], params["attn"])
+    h = jax.random.normal(jax.random.PRNGKey(8), (1, SEQ, MODEL["d_model"]))
+    w = jax.random.normal(jax.random.PRNGKey(9), h.shape)
+
+    def grads(use_flash):
+        step = StepConfig(use_flash=use_flash, flash_block_q=bq,
+                          flash_block_k=bk)
+        return jax.grad(lambda p: jnp.sum(w * granite_hybrid._attention_block(
+            h, p, arch, step)))(lp)
+
+    flash, plain = grads(True), grads(False)
+    for path, ref in jax.tree_util.tree_leaves_with_path(plain):
+        got = _leaf(flash, path)
+        gap = jnp.linalg.norm(got - ref) / jnp.linalg.norm(ref)
+        assert float(gap) < GRAD_RTOL, jax.tree_util.keystr(path)
+
+
+def _leaf(tree, path):
+    for key in path:
+        tree = tree[key.key]
+    return tree
+
+
 def test_layer_pattern_puts_attention_at_the_published_indices():
     published = dataclasses.replace(configs.get("granite_4_0_h_micro"),
                                     n_layers=40)
@@ -207,7 +237,14 @@ def test_each_build_counts_its_layers():
     assert got["model.mamba_layers"] == 9
     assert got["model.attention_layers"] == 1
     assert got["ssd.pallas_calls"] == 9
+    assert "flash.bwd_pallas_calls" not in got
+    before = reg.snapshot()
+    build_workload("train_step", smoke, step=StepConfig(use_flash=True),
+                   batch_size=1, seq_len=16)
+    assert reg.delta(before)["counters"]["flash.bwd_pallas_calls"] == 1
     before = reg.snapshot()
     build_workload("train_step", smoke, step=StepConfig(), batch_size=1,
                    seq_len=16)
-    assert "ssd.pallas_calls" not in reg.delta(before)["counters"]
+    got = reg.delta(before)["counters"]
+    assert "ssd.pallas_calls" not in got
+    assert "flash.bwd_pallas_calls" not in got

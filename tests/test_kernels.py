@@ -127,6 +127,41 @@ def test_attention_bf16():
                                atol=3e-2)
 
 
+@pytest.mark.parametrize("hq,hkv,s,causal,window,bq,bk,dtype", [
+    (4, 4, 256, True, None, 64, 64, jnp.float32),
+    (8, 2, 256, True, None, 64, 128, jnp.float32),     # bq != bk
+    (8, 1, 256, True, None, 128, 64, jnp.float32),     # MQA, bq > bk
+    (4, 4, 256, False, None, 64, 64, jnp.float32),
+    (8, 2, 256, False, None, 128, 64, jnp.float32),
+    (8, 2, 256, True, 48, 64, 64, jnp.float32),        # sliding window
+    (8, 1, 200, True, None, 64, 64, jnp.float32),      # S padded to 256
+    (8, 2, 256, True, None, 128, 128, jnp.bfloat16),
+])
+def test_attention_gradients_match_reference(hq, hkv, s, causal, window, bq,
+                                             bk, dtype):
+    """dq, dk, dv of the kernel's Pallas backward against ``jax.vjp`` of
+    the reference, in f32 at HIGHEST (bf16 inputs: the reference upcasts,
+    the kernel's gradients are rounded to bf16)."""
+    ks = jax.random.split(jax.random.fold_in(KEY, 1000 * hq + s), 4)
+    q = jax.random.normal(ks[0], (1, hq, s, 32), dtype)
+    k = jax.random.normal(ks[1], (1, hkv, s, 32), dtype)
+    v = jax.random.normal(ks[2], (1, hkv, s, 32), dtype)
+    g = jax.random.normal(ks[3], (1, hq, s, 32), dtype)
+    _, kernel_vjp = jax.vjp(lambda *a: flash_attention(
+        *a, causal=causal, window=window, bq=bq, bk=bk, interpret=True),
+        q, k, v)
+    with jax.default_matmul_precision("highest"):
+        _, ref_vjp = jax.vjp(lambda *a: attention_ref(
+            *a, causal=causal, window=window),
+            *(x.astype(jnp.float32) for x in (q, k, v)))
+        want = ref_vjp(g.astype(jnp.float32))
+    tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+    for name, got, ref in zip("qkv", kernel_vjp(g), want):
+        assert got.dtype == dtype
+        err = jnp.max(jnp.abs(got.astype(jnp.float32) - ref))
+        assert float(err) <= tol * float(jnp.max(jnp.abs(ref))), name
+
+
 def test_online_softmax_matches_xla_chunked():
     """The model zoo's XLA q-chunked path vs the kernel (same algorithm)."""
     from repro.models.layers import _attend

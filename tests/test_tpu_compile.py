@@ -75,6 +75,28 @@ def test_flash_attention_compiles(shapes, heads, kv_heads, dim, tile):
         q_, k_, v_, causal=True, bq=tile, bk=tile), q, kv, kv)
 
 
+@pytest.mark.parametrize("batch,seq,tile", [
+    (2, 2048, 256),   # granite-3-2b's attention, bq 256
+    (1, 8192, 512),   # granite-4.0-h-micro's attention layers at 8k
+])
+def test_flash_attention_backward_compiles(shapes, batch, seq, tile):
+    """The differentiated kernel at 32/8 heads of 64: the module holds the
+    two Pallas backward kernels, and no (S, S) scores (f32 scores would
+    be 8.6 GB at 8192) among its temporaries."""
+    q = shapes((batch, 32, seq, 64), jnp.bfloat16)
+    kv = shapes((batch, 8, seq, 64), jnp.bfloat16)
+
+    def loss(q_, k_, v_):
+        out = flash_attention(q_, k_, v_, causal=True, bq=tile, bk=512)
+        return jnp.sum(out.astype(jnp.float32))
+
+    compiled = _compile(jax.value_and_grad(loss, argnums=(0, 1, 2)),
+                        q, kv, kv)
+    text = compiled.as_text()
+    assert "flash_bwd_dkv" in text and "flash_bwd_dq" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
 def test_matmul_bf16_4096_compiles(shapes):
     a = shapes((4096, 4096), jnp.bfloat16)
     _compile(lambda x, y: matmul_pallas(x, y, bm=512, bn=512, bk=512), a, a)
